@@ -23,10 +23,12 @@ Times `DistSimulation.run` on a forced 8-host-device 4x2 mesh across the
         --comm-json BENCH_comm.json
 
 The forced host-device override must be set before jax initializes, so this
-module re-executes itself in a subprocess when the current process does not
-already have 8 devices. Rows embed the serialized `SimSpec` measured where
-the workload is spec-expressible (the imbalanced slab is carved from the
-lwfa scenario's particle set by an alive-mask — recorded in meta).
+module re-executes itself in a CPU-only subprocess (`JAX_PLATFORMS=cpu`, 8
+emulated host devices) when the host does not expose 8 devices — decided
+without importing jax, so the parent never holds a chip the child needs.
+Rows embed the serialized `SimSpec` measured where the workload is
+spec-expressible (the imbalanced slab is carved from the lwfa scenario's
+particle set by an alive-mask — recorded in meta).
 
 Schema: {"meta": {...}, "results": {"uniform": {<variant>: {us, speedup,
 spec}}, "imbalanced_lwfa": {...}}, "acceptance": {...}}
@@ -61,18 +63,20 @@ IMBALANCED_VARIANTS = {
 
 
 def _needs_respawn(n: int | None = None) -> bool:
+    # decided without importing jax: a parent holding the chip would starve
+    # the child; with enough real devices the sweep runs in-process
     if os.environ.get(_CHILD_ENV) == "1":
         return False
-    import jax
+    from repro.launch.devices import device_count_without_jax
 
-    return jax.device_count() < (n or MESH_SHAPE[0] * MESH_SHAPE[1])
+    return device_count_without_jax() < (n or MESH_SHAPE[0] * MESH_SHAPE[1])
 
 
 def _respawn(json_path: str | None, *, smoke: bool = False, n: int | None = None) -> None:
-    n = n or MESH_SHAPE[0] * MESH_SHAPE[1]
-    env = dict(os.environ)
+    from repro.launch.devices import emulated_devices_env
+
+    env = emulated_devices_env(n or MESH_SHAPE[0] * MESH_SHAPE[1])
     env[_CHILD_ENV] = "1"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n} " + env.get("XLA_FLAGS", "")
     cmd = [sys.executable, "-m", "benchmarks.comm_sweep"]
     if smoke:
         cmd += ["--smoke"]

@@ -15,11 +15,13 @@ the distributed driver through the same `make_simulation` facade) and the
 result row records the exact serialized `SimSpec` it measured.
 
 The forced host-device override must be set before jax initializes, so this
-module re-executes itself in a subprocess when the current process does not
-already have 8 devices. Both drivers run the identical shard_map step and
-identical policy thresholds (wall-clock trigger disabled); the measured
-delta is loop control flow: per-step dispatch of the sharded program +
-device->host stat syncs vs one compiled window.
+module re-executes itself in a CPU-only subprocess (`JAX_PLATFORMS=cpu`, 8
+emulated host devices) when the host does not expose 8 devices — decided
+without importing jax, so the parent never holds a chip the child needs.
+Both drivers run the identical shard_map step and identical policy
+thresholds (wall-clock trigger disabled); the measured delta is loop
+control flow: per-step dispatch of the sharded program + device->host stat
+syncs vs one compiled window.
 
 Schema: {"meta": {..., "scenario": name}, "results": {"incremental":
 {host_us, device_us, speedup, spec}}, "acceptance":
@@ -44,18 +46,20 @@ _CHILD_ENV = "_REPRO_DIST_SWEEP_CHILD"
 
 
 def _needs_respawn() -> bool:
+    # decided without importing jax: a parent holding the chip would starve
+    # the child; with enough real devices the sweep runs in-process
     if os.environ.get(_CHILD_ENV) == "1":
         return False
-    import jax
+    from repro.launch.devices import device_count_without_jax
 
-    return jax.device_count() < MESH_SHAPE[0] * MESH_SHAPE[1]
+    return device_count_without_jax() < MESH_SHAPE[0] * MESH_SHAPE[1]
 
 
 def _respawn(json_path: str | None, scenario_name: str) -> None:
-    n = MESH_SHAPE[0] * MESH_SHAPE[1]
-    env = dict(os.environ)
+    from repro.launch.devices import emulated_devices_env
+
+    env = emulated_devices_env(MESH_SHAPE[0] * MESH_SHAPE[1])
     env[_CHILD_ENV] = "1"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n} " + env.get("XLA_FLAGS", "")
     cmd = [sys.executable, "-m", "benchmarks.dist_sweep", "--scenario", scenario_name]
     if json_path:
         cmd += ["--json", json_path]

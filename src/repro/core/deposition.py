@@ -146,7 +146,7 @@ def binned_shape_factors(pos, values, layout: BinnedLayout, *, grid_shape, order
 
 def _default_bin_matmul(a, b):
     """rhocell[c] = A_c^T B_c — the sum-of-outer-products == MOPA tile."""
-    return jnp.einsum("cpm,cpn->cmn", a, b)
+    return jnp.einsum("cpm,cpn->cmn", a, b, precision=sf.CONTRACTION_PRECISION)
 
 
 @partial(

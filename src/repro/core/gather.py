@@ -128,7 +128,7 @@ def _gather_matrix_jit(pos, grid_padded, layout: BinnedLayout, *, grid_shape, or
         e_bins = bin_gather_op(wx, byz, neigh).astype(pos_b.dtype) * valid
     else:
         # H[c,p,m] = sum_n B[c,p,n] G[c,m,n]; E[c,p] = sum_m wx[c,p,m] H[c,p,m]
-        h = jnp.einsum("cpn,cmn->cpm", byz, neigh)
+        h = jnp.einsum("cpn,cmn->cpm", byz, neigh, precision=sf.CONTRACTION_PRECISION)
         e_bins = jnp.sum(wx * h, axis=-1) * valid
 
     # scatter back to particle order via the slot map
@@ -185,7 +185,7 @@ def _fused_gather_xla_bins(d, padded_fields, *, grid_shape, order, guard):
             wz = w_s[2] if stagger[2] else w_u[2]
             byz[key] = (wy[..., :, None] * wz[..., None, :]).reshape(n_cells, cap, ty * tz)
         wx = w_s[0] if stagger[0] else w_u[0]
-        h = jnp.einsum("cpn,cmn->cpm", byz[key], neigh)
+        h = jnp.einsum("cpn,cmn->cpm", byz[key], neigh, precision=sf.CONTRACTION_PRECISION)
         comps.append(jnp.sum(wx * h, axis=-1))
     return jnp.stack(comps, axis=-1)  # (C, cap, 6)
 

@@ -24,6 +24,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.shape_functions import CONTRACTION_PRECISION
+
 
 @partial(jax.jit, static_argnames=("n_bins", "capacity"))
 def matrix_scatter_add(indices, updates, *, n_bins: int, capacity: int, weights=None):
@@ -61,7 +63,7 @@ def matrix_scatter_add(indices, updates, *, n_bins: int, capacity: int, weights=
     binned_w = binned_w.at[dst].set(w[order])[:-1].reshape(n_bins, capacity)
 
     # --- stage 2: dense per-bin contraction (batched 1 x cap @ cap x D).
-    out = jnp.einsum("bc,bcd->bd", binned_w, binned_u)
+    out = jnp.einsum("bc,bcd->bd", binned_w, binned_u, precision=CONTRACTION_PRECISION)
 
     # --- stage 3: exact overflow fallback (rare when capacity is sized
     # like the GPMA headroom; measured in tests/benchmarks).

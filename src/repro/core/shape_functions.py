@@ -27,7 +27,15 @@ relative to the particle's cell index.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+#: Precision of every bin contraction (deposition, gather, and their
+#: kernels). TPU's default f32 matmul is one bf16 pass, whose ~1e-3
+#: relative error misses the 1e-5 bound the float64 scatter oracle holds
+#: deposition and gather to; HIGHEST runs the f32 contraction in full.
+#: A no-op on CPU.
+CONTRACTION_PRECISION = jax.lax.Precision.HIGHEST
 
 # (order, staggered) -> (n_taps, base_offset)
 SUPPORT: dict[tuple[int, bool], tuple[int, int]] = {
@@ -144,6 +152,34 @@ def packed_axis_weights(d, order: int):
     return {
         (axis, staggered): shape_weights_window(
             d[..., axis], order, staggered, n_taps=t, base=base
+        )
+        for axis in (0, 1, 2)
+        for staggered in (False, True)
+    }
+
+
+def lane_axis_weights(d, order: int):
+    """The six weight sets of `packed_axis_weights` in the layout a TPU
+    kernel body can build: ``d`` is a ``(CB, cap, 3)`` slab (array or
+    Pallas ref); the x sets are ``(CB, cap, T)`` and the y and z sets live
+    on the flattened ``(CB, cap, T*T)`` outer-product axis (lane ``j*T + k``
+    holds tap j of y and tap k of z), so ``wy * wz`` is an elementwise
+    multiply. Each lane's tap index comes from a broadcasted iota — Mosaic
+    lowers no stack or reshape of a lane axis, and this needs neither.
+    Same values as `packed_axis_weights`, element for element: every tap
+    offset ``base + shift + j`` is exact in float32."""
+    t, base = unified_support(order)
+    cb, cap, _ = d.shape
+
+    def lane_index(n):
+        return jax.lax.broadcasted_iota(jnp.int32, (cb, cap, n), 2).astype(jnp.float32)
+
+    flat = lane_index(t * t)
+    tap_y = jnp.floor((flat + 0.5) * (1.0 / t))
+    taps = (lane_index(t), tap_y, flat - tap_y * t)
+    return {
+        (axis, staggered): bspline(
+            order, d[:, :, axis : axis + 1] - (taps[axis] + (base + 0.5 * staggered))
         )
         for axis in (0, 1, 2)
         for staggered in (False, True)

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size_compat
 
 # Out-of-range headroom for position quantization, in grid cells: CFL bounds
 # a particle's per-step motion below one cell, so any coordinate of a
@@ -81,7 +80,7 @@ def _compress_one(g, r, axis_name):
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = quantize_fixed(g32, scale, qmin=-127, qmax=127, dtype=jnp.int8)
     new_r = g32 - dequantize_fixed(q, scale)
-    n = axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     summed = lax.psum(q.astype(jnp.int32), axis_name).astype(jnp.float32) * scale / n
     return summed.astype(g.dtype), new_r
 

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_compat
-
 
 def pipeline_forward(stage_params, microbatches, stage_fn, *, mesh, axis_name: str = "pipe"):
     """Run microbatches through staged layers.
@@ -64,7 +62,7 @@ def pipeline_forward(stage_params, microbatches, stage_fn, *, mesh, axis_name: s
         return lax.psum(outputs, axis_name)
 
     spec_params = jax.tree.map(lambda _: P(axis_name), stage_params)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(spec_params, P()), out_specs=P(), check_vma=False
     )
     return fn(stage_params, microbatches)

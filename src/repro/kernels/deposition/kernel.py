@@ -31,9 +31,10 @@ batched dot on the MXU. The grid tiles the cell axis; block sizes come from
 the shared VMEM-budget autotuner (kernels/common.py). Capacity should be a
 multiple of 8 (lane alignment; 128 for full MXU depth — choose_capacity()).
 
-Weight evaluation is `shape_functions.shape_weights_window` — the same
-function the pure-JAX reference uses; tap offsets are numpy constants so it
-traces inside the kernel body (no iota).
+Weight evaluation is `shape_functions.lane_axis_weights`: the same
+B-spline the pure-JAX reference evaluates through `shape_weights_window`,
+with the taps on the lane axis (a broadcasted iota), so the kernel body
+needs no stack, reshape or scatter — none of which Mosaic lowers.
 """
 
 from __future__ import annotations
@@ -42,11 +43,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.shape_functions import shape_weights_window, support, unified_support
+from repro.core.shape_functions import CONTRACTION_PRECISION, lane_axis_weights, unified_support
 from repro.kernels.common import (
-    DEFAULT_VMEM_BUDGET_BYTES,
     choose_block_cells,
     resolve_interpret,
+    vmem_bytes,
 )
 
 
@@ -58,6 +59,7 @@ def _mxu_kernel(a_ref, b_ref, o_ref):
         b,
         dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=o_ref.dtype,
+        precision=CONTRACTION_PRECISION,
     )
 
 
@@ -74,7 +76,7 @@ def bin_outer_product_pallas(
     block_cells: int | None = None,
     mode: str = "mxu",
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """Batched per-bin contraction via pl.pallas_call.
 
@@ -86,7 +88,8 @@ def bin_outer_product_pallas(
 
     interpret = resolve_interpret(interpret)
     if block_cells is None:
-        per_cell = cap * (m + n) * 4 + m * n * 4
+        # double-buffered in/out blocks
+        per_cell = 2 * vmem_bytes((cap, m), (cap, n), (m, n), tiled=not interpret)
         block_cells = choose_block_cells(
             c, per_cell, vmem_budget_bytes=vmem_budget_bytes, interpret=interpret
         )
@@ -112,63 +115,58 @@ def bin_outer_product_pallas(
 # ---------------------------------------------------------------------------
 
 
+def _current_tiles(d_ref, val_ref, order: int):
+    """Steps (b)+(c) of the fused deposition inside a kernel body: the six
+    1-D weight sets on the VPU and the three shared-weight MXU
+    contractions, each producing component k's packed ``(CB, T, T*T)``
+    rhocell tile on the order's unified window directly.
+
+    Every operand lives on the unified window (off-support taps are exact
+    zeros — shape_functions.shape_weights_window), with the taps on lanes
+    (`lane_axis_weights`), so the tile needs neither a reshape nor an
+    embedding scatter: Mosaic lowers neither. The padded taps cost nothing
+    on the MXU, whose tiles are 128 lanes wide anyway."""
+    w = lane_axis_weights(d_ref, order)
+    tiles = []
+    for comp in range(3):
+        a = w[(0, comp == 0)] * val_ref[:, :, comp : comp + 1]  # (CB, cap, T)
+        byz = w[(1, comp == 1)] * w[(2, comp == 2)]              # (CB, cap, T*T)
+        tiles.append(jax.lax.dot_general(
+            a,
+            byz,
+            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=CONTRACTION_PRECISION,
+        ))
+    return tiles
+
+
 def _make_fused_kernel(order: int):
-    t, base = unified_support(order)
-
     def kernel(d_ref, val_ref, o_ref):
-        d = d_ref[...]      # (CB, cap, 3) fractional in-cell offsets
-        val = val_ref[...]  # (CB, cap, 3) q*w*v per component, gaps zeroed
-        cb, cap = d.shape[0], d.shape[1]
-
-        # (b) six 1-D weight sets on the VPU — unstaggered + staggered per
-        # axis, each on its TRUE support so the contractions below carry no
-        # padded FLOPs (matters under the interpreter; on the MXU the small
-        # dots pad to hardware tiles regardless).
-        w = {}
-        for axis in range(3):
-            da = d[..., axis]
-            for staggered in (False, True):
-                nt, b = support(order, staggered)
-                w[(axis, staggered)] = shape_weights_window(
-                    da, order, staggered, n_taps=nt, base=b
-                )
-
-        # (c) three shared-weight MXU contractions (component k staggered on
-        # axis k only), each (d) embedded at its static offset inside the
-        # packed (CB, 3, T, T*T) unified-window rhocell tile.
-        out = jnp.zeros((cb, 3, t, t, t), o_ref.dtype)
-        for comp in range(3):
-            wx = w[(0, comp == 0)]
-            wy = w[(1, comp == 1)]
-            wz = w[(2, comp == 2)]
-            (tx, bx) = support(order, comp == 0)
-            (ty, by) = support(order, comp == 1)
-            (tz, bz) = support(order, comp == 2)
-            a = wx * val[..., comp][..., None]                       # (CB, cap, tx)
-            byz = (wy[..., :, None] * wz[..., None, :]).reshape(cb, cap, ty * tz)
-            res = jax.lax.dot_general(
-                a,
-                byz,
-                dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=o_ref.dtype,
-            )
-            ox, oy, oz = bx - base, by - base, bz - base
-            out = out.at[:, comp, ox : ox + tx, oy : oy + ty, oz : oz + tz].set(
-                res.reshape(cb, tx, ty, tz)
-            )
-        o_ref[...] = out.reshape(cb, 3, t, t * t)
+        # (d) each component's tile is written straight into its slot of
+        # the packed (CB, 3, T, T*T) output block
+        for comp, tile in enumerate(_current_tiles(d_ref, val_ref, order)):
+            o_ref[:, comp] = tile.astype(o_ref.dtype)
 
     return kernel
 
 
-def fused_deposition_bytes_per_cell(cap: int, order: int) -> int:
-    """VMEM working set of one cell in the fused kernel, in bytes: the two
-    (cap, 3) input slabs, six (cap, T) weight sets, the (cap, T) and
-    (cap, T*T) operands of the live contraction, and the packed (3, T, T*T)
-    tile twice (the zero-padded accumulator plus the output block)."""
+def fused_deposition_bytes_per_cell(cap: int, order: int, *, tiled: bool = False) -> int:
+    """VMEM working set of one cell in the fused kernel, in bytes.
+
+    ``tiled`` (compiled Mosaic): the two (cap, 3) input slabs and the
+    packed (3, T, T*T) output tile, each twice (double-buffered pipeline),
+    plus the weight sets — two (cap, T), four (cap, T*T) — the live
+    (cap, T) and (cap, T*T) operands and three (T, T*T) result tiles, all
+    (8, 128)-padded. Untiled: the interpreter's calibrated count (inputs,
+    six (cap, T) weight sets, one operand pair, the packed tile twice)."""
     t, _ = unified_support(order)
     n = t * t
-    return 4 * (2 * cap * 3 + 6 * cap * t + cap * (t + n) + 2 * 3 * t * n)
+    if not tiled:
+        return 4 * (2 * cap * 3 + 6 * cap * t + cap * (t + n) + 2 * 3 * t * n)
+    io = vmem_bytes((cap, 3), (cap, 3), (3, t, n), tiled=True)
+    work = vmem_bytes(*[(cap, t)] * 3, *[(cap, n)] * 5, *[(t, n)] * 3, tiled=True)
+    return 2 * io + work
 
 
 def fused_deposition_pallas(
@@ -178,7 +176,7 @@ def fused_deposition_pallas(
     order: int,
     block_cells: int | None = None,
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """Fused Jx/Jy/Jz deposition contraction.
 
@@ -197,7 +195,7 @@ def fused_deposition_pallas(
     if block_cells is None:
         block_cells = choose_block_cells(
             c,
-            fused_deposition_bytes_per_cell(cap, order),
+            fused_deposition_bytes_per_cell(cap, order, tiled=not interpret),
             vmem_budget_bytes=vmem_budget_bytes,
             interpret=interpret,
             taps=t,
@@ -228,63 +226,35 @@ def _make_fused_reduced_kernel(order: int, nz: int, guard: int):
     g = guard
 
     def kernel(d_ref, val_ref, o_ref):
-        d = d_ref[...]      # (BC*nz, cap, 3) — BC whole z-columns of cells
-        val = val_ref[...]
-        cb, cap = d.shape[0], d.shape[1]
-        bc = cb // nz
+        bc = d_ref.shape[0] // nz  # BC whole z-columns of cells
 
-        # (b) six 1-D weight sets on the VPU, identical to _make_fused_kernel
-        w = {}
-        for axis in range(3):
-            da = d[..., axis]
-            for staggered in (False, True):
-                nt, b = support(order, staggered)
-                w[(axis, staggered)] = shape_weights_window(
-                    da, order, staggered, n_taps=nt, base=b
-                )
-
-        # (c) the three shared-weight MXU contractions, then (d) the
-        # rhocell z-pass *in-kernel*: because cells are laid out z-fastest,
-        # a block of whole columns keeps every shifted add of
+        # (b)+(c) as in the packed kernel, then (d) the rhocell z-pass
+        # *in-kernel*: because cells are laid out z-fastest, a block of
+        # whole columns keeps every shifted add of
         # reduce_rhocell_separable's acc_z stage inside the block — the
         # packed (C, 3, T, T*T) tile never exists in HBM, and the output
-        # shrinks from 3*T^3 to 3*T^2*(nz+2g)/nz floats per cell. Tap
-        # adds run in ascending true-support order, the same per-element
-        # accumulation sequence as the two-step reference (off-support
-        # unified taps only ever add exact zeros there).
-        acc = jnp.zeros((bc, 3, nz + 2 * g, t, t), o_ref.dtype)
-        for comp in range(3):
-            wx = w[(0, comp == 0)]
-            wy = w[(1, comp == 1)]
-            wz = w[(2, comp == 2)]
-            (tx, bx) = support(order, comp == 0)
-            (ty, by) = support(order, comp == 1)
-            (tz, bz) = support(order, comp == 2)
-            a = wx * val[..., comp][..., None]                       # (CB, cap, tx)
-            byz = (wy[..., :, None] * wz[..., None, :]).reshape(cb, cap, ty * tz)
-            res = jax.lax.dot_general(
-                a,
-                byz,
-                dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=o_ref.dtype,
-            )
-            rho = res.reshape(bc, nz, tx, ty, tz)
-            ox, oy = bx - base, by - base
-            for c in range(tz):
-                acc = acc.at[
-                    :, comp, g + bz + c : g + bz + c + nz, ox : ox + tx, oy : oy + ty
-                ].add(rho[..., c])
-        o_ref[...] = acc
+        # shrinks from 3*T^3 to 3*T^2*(nz+2g)/nz floats per cell. Tap adds
+        # run in ascending unified-window order into the output block, the
+        # same per-element accumulation sequence as the two-step reference.
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        for comp, tile in enumerate(_current_tiles(d_ref, val_ref, order)):
+            rho = tile.reshape(bc, nz, t, t, t)
+            for c in range(t):
+                z0 = g + base + c
+                o_ref[:, comp, z0 : z0 + nz] += rho[..., c].astype(o_ref.dtype)
 
     return kernel
 
 
-def fused_reduced_bytes_per_column(cap: int, order: int, nz: int, guard: int) -> int:
+def fused_reduced_bytes_per_column(
+    cap: int, order: int, nz: int, guard: int, *, tiled: bool = False
+) -> int:
     """VMEM working set of one z-column in the epilogue-fused kernel: nz
     cells of the fused working set plus the column's (3, nz+2g, T, T)
     accumulator."""
     t, _ = unified_support(order)
-    return nz * fused_deposition_bytes_per_cell(cap, order) + 4 * 3 * (nz + 2 * guard) * t * t
+    acc = vmem_bytes((3 * (nz + 2 * guard), t, t), tiled=tiled)
+    return nz * fused_deposition_bytes_per_cell(cap, order, tiled=tiled) + acc
 
 
 def fused_deposition_reduced_pallas(
@@ -296,7 +266,7 @@ def fused_deposition_reduced_pallas(
     guard: int,
     block_cols: int | None = None,
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """Fused deposition with the rhocell z-reduction folded in-kernel.
 
@@ -319,7 +289,7 @@ def fused_deposition_reduced_pallas(
     if block_cols is None:
         block_cols = choose_block_cells(
             n_cols,
-            fused_reduced_bytes_per_column(cap, order, nz, g),
+            fused_reduced_bytes_per_column(cap, order, nz, g, tiled=not interpret),
             vmem_budget_bytes=vmem_budget_bytes,
             interpret=interpret,
             taps=t,

@@ -7,12 +7,19 @@ reference differ only in who runs the contraction (MXU dot vs einsum).
 
 import jax.numpy as jnp
 
-from repro.core.shape_functions import shape_weights_window, unified_support
+from repro.core.shape_functions import (
+    CONTRACTION_PRECISION,
+    shape_weights_window,
+    unified_support,
+)
 
 
 def bin_outer_product_ref(a, b):
     """out[c] = A_c^T @ B_c. a: (C, cap, M), b: (C, cap, N) -> (C, M, N)."""
-    return jnp.einsum("cpm,cpn->cmn", a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum(
+        "cpm,cpn->cmn", a, b, preferred_element_type=jnp.float32,
+        precision=CONTRACTION_PRECISION,
+    )
 
 
 def fused_bin_deposit_ref(d, val, *, order: int):
@@ -30,7 +37,10 @@ def fused_bin_deposit_ref(d, val, *, order: int):
         wz = shape_weights_window(d[..., 2], order, comp == 2, n_taps=t, base=base)
         a = wx * val[..., comp][..., None]
         byz = (wy[..., :, None] * wz[..., None, :]).reshape(c, cap, t * t)
-        packed.append(jnp.einsum("cpm,cpn->cmn", a, byz, preferred_element_type=jnp.float32))
+        packed.append(jnp.einsum(
+            "cpm,cpn->cmn", a, byz, preferred_element_type=jnp.float32,
+            precision=CONTRACTION_PRECISION,
+        ))
     return jnp.stack(packed, axis=1)
 
 

@@ -25,6 +25,10 @@ Backend names are spec-level (`DepositionSpec.backend`):
                            real call (synthetic inputs at the call's exact
                            shapes) and persist the winner.
 
+On a TPU no Pallas backend is offered yet (see `_pallas_ok`): every
+"auto" resolves to ``"xla"`` there. A candidate that fails to compile
+raises — nothing is caught and skipped.
+
 Resolution of a *forced* name never fails sideways: if the name is not
 registered on the op (or unavailable for the key), the best available
 backend of priority <= the forced one is used — forcing
@@ -183,15 +187,41 @@ def _trace_clean() -> bool:
     block_until_ready would be a no-op on tracers)."""
     import jax
 
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:  # renamed/moved in a future jax: assume traced
-        try:
-            from jax._src import core as _core
+    return jax.core.trace_ctx.is_top_level()
 
-            return bool(_core.trace_state_clean())
-        except Exception:
-            return False
+
+def _make_key(
+    op: str,
+    *,
+    order: int,
+    grid_shape=None,
+    capacity: int = 0,
+    n_bins: int | None = None,
+    dtype: str = "float32",
+    interpret: bool | None = None,
+    sharded: bool = False,
+    batch: int = 1,
+) -> DispatchKey:
+    """The key of one call on the current platform (n_bins defaults to the
+    grid's cell count)."""
+    import jax
+
+    if grid_shape is not None:
+        grid_shape = tuple(int(s) for s in grid_shape)
+        if n_bins is None:
+            n_bins = grid_shape[0] * grid_shape[1] * grid_shape[2]
+    return DispatchKey(
+        op=op,
+        order=int(order),
+        grid_shape=grid_shape,
+        capacity=int(capacity),
+        n_bins=int(n_bins or 0),
+        dtype=str(dtype),
+        platform=jax.default_backend(),
+        interpret=resolve_interpret(interpret),
+        sharded=bool(sharded),
+        batch=int(batch),
+    )
 
 
 def resolve(
@@ -227,23 +257,9 @@ def resolve(
     so a later eager call still gets to measure. Callers that trace with
     "auto" should ``prewarm`` their keys eagerly first.
     """
-    import jax
-
-    if grid_shape is not None:
-        grid_shape = tuple(int(s) for s in grid_shape)
-        if n_bins is None:
-            n_bins = grid_shape[0] * grid_shape[1] * grid_shape[2]
-    key = DispatchKey(
-        op=op,
-        order=int(order),
-        grid_shape=grid_shape,
-        capacity=int(capacity),
-        n_bins=int(n_bins or 0),
-        dtype=str(dtype),
-        platform=jax.default_backend(),
-        interpret=resolve_interpret(interpret),
-        sharded=bool(sharded),
-        batch=int(batch),
+    key = _make_key(
+        op, order=order, grid_shape=grid_shape, capacity=capacity, n_bins=n_bins,
+        dtype=dtype, interpret=interpret, sharded=sharded, batch=batch,
     )
 
     memo_key = (key, requested)
@@ -358,6 +374,23 @@ def prewarm(
     }
 
 
+def describe(ops_: tuple[str, ...] | list[str], **key) -> dict[str, dict]:
+    """{op: {"offered": backend names by priority, "timings_us": the
+    persisted autotune medians or None}} at one shape key (``key`` as for
+    `prewarm`). Reads only: nothing is benchmarked or memoized."""
+    entries = _load_cache(cache_path(), quiet=True)
+    out = {}
+    for op in ops_:
+        k = _make_key(op, **key)
+        offered = [b for b in backends_for(op).values() if b.is_available(k)]
+        cached = entries.get(k.cache_key())
+        out[op] = {
+            "offered": [b.name for b in sorted(offered, key=lambda b: -b.priority)],
+            "timings_us": cached.get("timings_us") if isinstance(cached, dict) else None,
+        }
+    return out
+
+
 def demote(
     current: str,
     *,
@@ -411,25 +444,12 @@ def record(
     higher-quality measurements than the dispatcher's quick first-call
     probe — so the persisted choice and the published BENCH_* rows agree
     by construction."""
-    import jax
-
     unknown = set(timings_us) - set(BACKEND_PRIORITY)
     if unknown:
         raise ValueError(f"unknown backends in timings: {sorted(unknown)}")
-    if grid_shape is not None:
-        grid_shape = tuple(int(s) for s in grid_shape)
-        if n_bins is None:
-            n_bins = grid_shape[0] * grid_shape[1] * grid_shape[2]
-    key = DispatchKey(
-        op=op,
-        order=int(order),
-        grid_shape=grid_shape,
-        capacity=int(capacity),
-        n_bins=int(n_bins or 0),
-        dtype=str(dtype),
-        platform=jax.default_backend(),
-        interpret=resolve_interpret(interpret),
-        batch=int(batch),
+    key = _make_key(
+        op, order=order, grid_shape=grid_shape, capacity=capacity, n_bins=n_bins,
+        dtype=dtype, interpret=interpret, batch=batch,
     )
     winner = min(timings_us, key=timings_us.get)
     _merge_store(cache_path(), key.cache_key(), {
@@ -520,17 +540,30 @@ def _always(_key: DispatchKey) -> bool:
 
 def _pallas_ok(key: DispatchKey) -> bool:
     # pallas_call has no shard_map replication rule (on any platform), so
-    # ops traced inside a shard body can never route to Pallas. Otherwise:
-    # Mosaic compiles on TPU; everywhere else the kernels need the
-    # interpreter — with interpret forced off on a non-TPU platform the
-    # Pallas backends are unavailable and resolution falls back to XLA.
+    # ops traced inside a shard body can never route to Pallas. Everywhere
+    # but TPU the kernels need the interpreter — with interpret forced off
+    # on a non-TPU platform the Pallas backends are unavailable and
+    # resolution falls back to XLA.
     if key.sharded:
         return False
-    return key.platform == "tpu" or key.interpret
+    if key.platform == "tpu" and not key.interpret:
+        # Mosaic compiles every kernel of the registry for v5e except
+        # pallas_reduced (tests/test_tpu_compile.py), but none is offered
+        # on the chip: at 64^3 bins their (8, 128)-tiled (C, cap, 3)
+        # operands need 9-14 GiB of HBM — the uniform 64^3 order-3 window
+        # with them asks for "15.80G of 15.75G hbm" — and no kernel has
+        # passed the chip's oracle check. On a v5e both gather kernels
+        # never returned while they requested a 64 MiB scoped-VMEM limit,
+        # and a smoke with Pallas offered hung in a block_until_ready.
+        # ROADMAP C3: fix or delete.
+        return False
+    return key.interpret
 
 
 def _pallas_reduced_ok(key: DispatchKey) -> bool:
-    # the column-blocked kernel additionally needs the grid geometry
+    # the column-blocked kernel additionally needs the grid geometry (and
+    # Mosaic cannot lower its z-epilogue, which splits the lane axis of
+    # each (CB, T, T*T) tile into (T, T): "unsupported shape cast")
     return _pallas_ok(key) and key.grid_shape is not None
 
 
@@ -642,8 +675,11 @@ def _bin_gather_thunk(impl: str):
         if impl == "pallas":
             from repro.kernels.gather.ops import bin_gather as fn
         else:
+            from repro.core.shape_functions import CONTRACTION_PRECISION
+
             fn = lambda wx, byz, g: jnp.sum(
-                wx * jnp.einsum("cpn,cmn->cpm", byz, g), axis=-1
+                wx * jnp.einsum("cpn,cmn->cpm", byz, g, precision=CONTRACTION_PRECISION),
+                axis=-1,
             )
         fn = jax.jit(_bvmap(key, fn))
         return lambda: jax.block_until_ready(fn(wx, byz, g))

@@ -25,9 +25,11 @@ Two kernels live here.
       components (Ex..Bz) on the order's *unified* tap window
       (shape_functions.unified_support), E and B staggers packed together;
   (b) computes the six 1-D shape-weight sets (centered + staggered per
-      axis) in-kernel on the VPU via `shape_functions.packed_axis_weights`
-      — off-support taps are exactly 0, so the unified window changes
-      nothing but the (shared) operand shapes;
+      axis) in-kernel on the VPU — the values of
+      `shape_functions.packed_axis_weights`, built with the taps on lanes
+      (`lane_axis_weights`) so Mosaic lowers them; off-support taps are
+      exactly 0, so the unified window changes nothing but the (shared)
+      operand shapes;
   (c) reuses the four distinct wy⊗wz tap products across the component
       pairs that share them and runs the six MXU contractions against the
       packed neighborhoods;
@@ -44,11 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.gather import EB_STAGGERS
-from repro.core.shape_functions import packed_axis_weights, unified_support
+from repro.core.shape_functions import CONTRACTION_PRECISION, lane_axis_weights, unified_support
 from repro.kernels.common import (
-    DEFAULT_VMEM_BUDGET_BYTES,
     choose_block_cells,
     resolve_interpret,
+    vmem_bytes,
 )
 
 
@@ -58,7 +60,8 @@ def _gather_kernel(wx_ref, byz_ref, g_ref, o_ref):
     g = g_ref[...]      # (CB, M, N)
     # H[c,p,m] = sum_n byz[c,p,n] * G[c,m,n]   (MXU batched matmul)
     h = jax.lax.dot_general(
-        byz, g, dimension_numbers=(((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        byz, g, dimension_numbers=(((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+        precision=CONTRACTION_PRECISION,
     )
     # e[c,p] = sum_m wx * H                    (VPU reduction)
     o_ref[...] = jnp.sum(wx * h, axis=-1)
@@ -71,14 +74,17 @@ def bin_gather_pallas(
     *,
     block_cells: int | None = None,
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """wx: (C, cap, M); byz: (C, cap, N); g: (C, M, N) -> (C, cap) values."""
     c, cap, m = wx.shape
     n = byz.shape[2]
     interpret = resolve_interpret(interpret)
     if block_cells is None:
-        per_cell = cap * (m + n + 1) * 4 + m * n * 4
+        # double-buffered in/out blocks plus the live (cap, M) H and wx*H
+        tiled = not interpret
+        io = vmem_bytes((cap, m), (cap, n), (m, n), (1, cap), tiled=tiled)
+        per_cell = 2 * io + vmem_bytes((cap, m), (cap, m), tiled=tiled)
         block_cells = choose_block_cells(
             c, per_cell, vmem_budget_bytes=vmem_budget_bytes, interpret=interpret
         )
@@ -105,50 +111,61 @@ def bin_gather_pallas(
 
 
 def _make_fused_gather_kernel(order: int):
-    t, _ = unified_support(order)
 
     def kernel(d_ref, g_ref, o_ref):
-        d = d_ref[...]  # (CB, cap, 3) fractional in-cell offsets
-        g = g_ref[...]  # (CB, 6, T, T*T) packed neighborhoods, Ex..Bz
-        cb, cap = d.shape[0], d.shape[1]
+        cb, cap, _ = d_ref.shape
 
         # (b) six 1-D weight sets on the VPU, one evaluation for all six
-        # components (every component is centered or staggered per axis)
-        w = packed_axis_weights(d, order)
+        # components (every component is centered or staggered per axis).
+        # Taps sit on lanes (shape_functions.lane_axis_weights): the y and
+        # z sets are evaluated directly on the flattened (T*T) outer-product
+        # axis, so the wy (x) wz products below are elementwise multiplies.
+        w = lane_axis_weights(d_ref, order)
 
         # (c) six MXU contractions sharing the weights; the four distinct
         # wy (x) wz products are built once and reused across the component
         # pairs that share them (Ey/Bz and Ez/By)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (cb, cap, 6), 2)
         byz = {}
-        outs = []
+        out = jnp.zeros((cb, cap, 6), jnp.float32)
         for comp, stagger in enumerate(EB_STAGGERS):
             key = (stagger[1], stagger[2])
             if key not in byz:
-                wy = w[(1, stagger[1])]
-                wz = w[(2, stagger[2])]
-                byz[key] = (wy[..., :, None] * wz[..., None, :]).reshape(cb, cap, t * t)
+                byz[key] = w[(1, stagger[1])] * w[(2, stagger[2])]
             # H[c,p,m] = sum_n byz[c,p,n] * G[c,comp,m,n]   (MXU)
             h = jax.lax.dot_general(
                 byz[key],
-                g[:, comp],
+                g_ref[:, comp],
                 dimension_numbers=(((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
+                precision=CONTRACTION_PRECISION,
             )
-            # e[c,p] = sum_m wx * H                         (VPU)
-            outs.append(jnp.sum(w[(0, stagger[0])] * h, axis=-1))
+            # e[c,p] = sum_m wx * H                         (VPU), placed on
+            # its lane of the packed (CB, cap, 6) tile
+            e = jnp.sum(w[(0, stagger[0])] * h, axis=-1, keepdims=True)
+            out = jnp.where(lane == comp, e, out)
         # (d) one packed per-bin value tile
-        o_ref[...] = jnp.stack(outs, axis=-1)
+        o_ref[...] = out.astype(o_ref.dtype)
 
     return kernel
 
 
-def fused_gather_bytes_per_cell(cap: int, order: int) -> int:
-    """VMEM working set of one cell in the fused gather kernel, in bytes:
-    the (cap, 3) slab, the packed (6, T, T*T) neighborhoods, six (cap, T)
-    weight sets, the four (cap, T*T) byz products, the (cap, T) live H, and
-    the (cap, 6) output tile twice (stack temp + output block)."""
+def fused_gather_bytes_per_cell(cap: int, order: int, *, tiled: bool = False) -> int:
+    """VMEM working set of one cell in the fused gather kernel, in bytes.
+
+    ``tiled`` (compiled Mosaic): the (cap, 3) slab, the packed (6, T, T*T)
+    neighborhoods and the (cap, 6) output tile, each twice (double-buffered
+    pipeline), plus the weight sets — two (cap, T), four (cap, T*T) — the
+    four (cap, T*T) byz products, the live (cap, T) H and wx*H, and the
+    (cap, 6) accumulator, all (8, 128)-padded. Untiled: the interpreter's
+    calibrated count."""
     t, _ = unified_support(order)
-    return 4 * (cap * 3 + 6 * t * t * t + 6 * cap * t + 4 * cap * t * t + cap * t + 2 * cap * 6)
+    n = t * t
+    if not tiled:
+        return 4 * (cap * 3 + 6 * t * t * t + 6 * cap * t + 4 * cap * t * t + cap * t + 2 * cap * 6)
+    io = vmem_bytes((cap, 3), (6, t, n), (cap, 6), tiled=True)
+    work = vmem_bytes(*[(cap, t)] * 4, *[(cap, n)] * 8, (cap, 6), tiled=True)
+    return 2 * io + work
 
 
 def fused_gather_pallas(
@@ -158,7 +175,7 @@ def fused_gather_pallas(
     order: int,
     block_cells: int | None = None,
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """Fused Ex/Ey/Ez/Bx/By/Bz gather contraction.
 
@@ -177,7 +194,7 @@ def fused_gather_pallas(
     if block_cells is None:
         block_cells = choose_block_cells(
             c,
-            fused_gather_bytes_per_cell(cap, order),
+            fused_gather_bytes_per_cell(cap, order, tiled=not interpret),
             vmem_budget_bytes=vmem_budget_bytes,
             interpret=interpret,
             taps=t,

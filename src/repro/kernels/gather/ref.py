@@ -3,12 +3,15 @@
 import jax.numpy as jnp
 
 from repro.core.gather import EB_STAGGERS
-from repro.core.shape_functions import packed_axis_weights
+from repro.core.shape_functions import CONTRACTION_PRECISION, packed_axis_weights
 
 
 def bin_gather_ref(wx, byz, g):
     """e[c,p] = sum_{m,n} wx[c,p,m] byz[c,p,n] g[c,m,n]."""
-    h = jnp.einsum("cpn,cmn->cpm", byz, g, preferred_element_type=jnp.float32)
+    h = jnp.einsum(
+        "cpn,cmn->cpm", byz, g, preferred_element_type=jnp.float32,
+        precision=CONTRACTION_PRECISION,
+    )
     return jnp.sum(wx * h, axis=-1)
 
 
@@ -25,6 +28,9 @@ def fused_bin_gather_ref(d, g, *, order: int):
         wy = w[(1, stagger[1])]
         wz = w[(2, stagger[2])]
         byz = (wy[..., :, None] * wz[..., None, :]).reshape(d.shape[0], d.shape[1], -1)
-        h = jnp.einsum("cpn,cmn->cpm", byz, g[:, comp], preferred_element_type=jnp.float32)
+        h = jnp.einsum(
+            "cpn,cmn->cpm", byz, g[:, comp], preferred_element_type=jnp.float32,
+            precision=CONTRACTION_PRECISION,
+        )
         outs.append(jnp.sum(w[(0, stagger[0])] * h, axis=-1))
     return jnp.stack(outs, axis=-1)

@@ -16,22 +16,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.shape_functions import CONTRACTION_PRECISION
 from repro.kernels.common import (
-    DEFAULT_VMEM_BUDGET_BYTES,
     choose_block_cells,
     resolve_interpret,
+    vmem_bytes,
 )
 
 
 def _segment_accum_kernel(w_ref, u_ref, o_ref):
-    w = w_ref[...]  # (VB, cap)
+    w = w_ref[...][:, None, :]  # (VB, 1, cap): Mosaic needs a non-contracting lhs dim
     u = u_ref[...]  # (VB, cap, DB)
     o_ref[...] = jax.lax.dot_general(
         w,
         u,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
         preferred_element_type=o_ref.dtype,
-    )
+        precision=CONTRACTION_PRECISION,
+    )[:, 0]
 
 
 def segment_accumulate_pallas(
@@ -41,7 +43,7 @@ def segment_accumulate_pallas(
     block_bins: int | None = None,
     block_d: int = 512,
     interpret: bool | None = None,
-    vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
+    vmem_budget_bytes: int | None = None,
 ) -> jax.Array:
     """w: (V, cap), u: (V, cap, D) -> (V, D) in u.dtype accumulated fp32."""
     v, cap = w.shape
@@ -49,7 +51,9 @@ def segment_accumulate_pallas(
     db = min(block_d, d)
     interpret = resolve_interpret(interpret)
     if block_bins is None:
-        per_bin = (cap + cap * db + db) * 4
+        # double-buffered in/out blocks; each (1, k) row counted as its own
+        # (8, 128) tile also covers the kernel's (VB, 1, k) relayouts
+        per_bin = 2 * vmem_bytes((1, cap), (cap, db), (1, db), tiled=not interpret)
         block_bins = choose_block_cells(
             v, per_bin, vmem_budget_bytes=vmem_budget_bytes, interpret=interpret
         )

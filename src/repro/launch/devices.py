@@ -68,3 +68,31 @@ def force_host_devices(n: int) -> None:
     flags = os.environ.get("XLA_FLAGS", "")
     if n > 1 and "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n} " + flags
+
+
+def device_count_without_jax() -> int:
+    """The devices jax will see in this process, worked out WITHOUT importing
+    jax — a parent that touches jax holds the chip, so a child it starts
+    could no longer reach it. Counts the TPU chips the host exposes (one
+    ``/dev/accel*`` or numbered ``/dev/vfio`` node per chip) unless
+    ``JAX_PLATFORMS`` pins the CPU; else the host-platform device count
+    forced through ``XLA_FLAGS`` (1 when none is forced)."""
+    import glob
+    import re
+
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        vfio = [p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()]
+        chips = len(glob.glob("/dev/accel*")) or len(vfio)
+        if chips:
+            return chips
+    forced = re.search(r"--xla_force_host_platform_device_count=(\d+)", os.environ.get("XLA_FLAGS", ""))
+    return int(forced.group(1)) if forced else 1
+
+
+def emulated_devices_env(n: int) -> dict:
+    """Environment for a child process that runs on ``n`` emulated host
+    devices: the CPU platform only, so it never contends for a chip."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n} " + env.get("XLA_FLAGS", "")
+    return env

@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.configs.registry import ARCH_IDS, SHAPES, cell_supported, get_config, input_specs  # noqa: E402
 from repro.distributed.sharding import Rules, rules_for, use_rules  # noqa: E402
 from repro.launch.flops import cell_costs  # noqa: E402
-from repro.launch.mesh import make_production_mesh, set_mesh_compat  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import decode_step, forward  # noqa: E402
 from repro.models.transformer import decode_state_axes, param_axes  # noqa: E402
 from repro.train import TrainConfig, init_train_state, make_train_step  # noqa: E402
@@ -258,7 +258,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool) -> dict:
     fn, args, in_shardings, rules, mesh, cfg = build_cell(arch, shape_name, multi_pod=multi_pod)
     record["params_b"] = cfg.param_count() / 1e9
 
-    with set_mesh_compat(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         t0 = time.time()
         lowered = jax.jit(fn, in_shardings=in_shardings).lower(*args)
         record["lower_s"] = round(time.time() - t0, 2)

@@ -1,25 +1,18 @@
 """Production mesh construction (brief: MULTI-POD DRY-RUN step 1).
 
 A FUNCTION, not a module-level constant, so importing this module never
-touches jax device state. The jax version-compat shims live in
-repro.compat (neutral layer — importable from core/distributed/pic without
-depending on launch); re-exported here for launch-side callers.
+touches jax device state.
 """
 
 from __future__ import annotations
 
-from repro.compat import (  # noqa: F401
-    axis_size_compat,
-    make_mesh_compat,
-    set_mesh_compat,
-    shard_map_compat,
-)
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_sizes(mesh) -> dict:

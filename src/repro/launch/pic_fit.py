@@ -29,6 +29,7 @@ from repro.grad.fit import fit_simulation
 from repro.grad.objectives import objective_names
 from repro.grad.params import LEARNABLE
 from repro.grad.spec import GradSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 
 
@@ -182,6 +183,7 @@ def run_smoke() -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         return run_smoke()
     return run_fit(args)

@@ -43,6 +43,7 @@ if _MESH_ARGV is not None:
     force_host_devices(_MESH_ARGV[0] * _MESH_ARGV[1])
 
 from repro.api import SimSpec, make_simulation, scenario, scenario_names  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def parse_fault(text: str) -> dict:
@@ -183,6 +184,7 @@ def run_ensemble(ensemble) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     src = ap.add_argument_group("run selection")
     src.add_argument("--scenario", default=None, metavar="NAME",

@@ -47,6 +47,7 @@ from repro.api.facade import (
     spec_signature,
 )
 from repro.api.spec import SimSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pic.ensemble import EnsembleSimulation, member_bundle
 
 __all__ = ["ExecutableCache", "SimJob", "SimService", "serve"]
@@ -457,6 +458,7 @@ def main(argv=None) -> int:
     parser.add_argument("--host", default="127.0.0.1")
     parser.add_argument("--port", type=int, default=8571)
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return asyncio.run(_smoke(args))

@@ -21,7 +21,6 @@ from repro.checkpoint import CheckpointManager
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.data import DataConfig, global_batch_at
 from repro.distributed import FailureInjector, Supervisor
-from repro.launch.mesh import make_mesh_compat, set_mesh_compat
 from repro.distributed.sharding import Rules, rules_for, use_rules
 from repro.models.transformer import param_axes
 from repro.optim import AdamWConfig, ScheduleConfig
@@ -47,7 +46,10 @@ def main() -> None:
     rules = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
-        mesh = make_mesh_compat(shape, ("data", "model")[: len(shape)])
+        mesh = jax.make_mesh(
+            shape, ("data", "model")[: len(shape)],
+            axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+        )
         table = rules_for(cfg, mode="train", multi_pod=False,
                           data_axis=shape[0], model_axis=shape[-1] if len(shape) > 1 else 1)
         rules = Rules(table, mesh)
@@ -85,7 +87,7 @@ def main() -> None:
         ctx.__enter__()
     try:
         if mesh is not None:
-            with set_mesh_compat(mesh):
+            with jax.set_mesh(mesh):
                 state, _ = sup.run(state, args.steps)
         else:
             state, _ = sup.run(state, args.steps)

@@ -51,9 +51,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh_compat, set_mesh_compat, shard_map_compat
 from repro.core import (
     ResortPolicy,
     SortPolicyConfig,
@@ -119,7 +119,9 @@ _window_trace_count = 0
 
 def make_pic_mesh(sx: int, sy: int):
     """An (sx, sy) device mesh on the default DistConfig axis names."""
-    return make_mesh_compat((sx, sy), ("data", "model"))
+    return jax.make_mesh(
+        (sx, sy), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
 
 
 def _mesh_axis_sizes(mesh, axes) -> int:
@@ -471,11 +473,11 @@ def make_dist_window(mesh, cfg: DistConfig, policy: SortPolicyConfig, n_steps: i
         P(),  # policy state
         P(),  # bundle (everything psum-reduced / replicated)
     )
-    # the replication checker (check_rep / check_vma) cannot track the scan
-    # carry's mixed replicated/sharded leaves on jax 0.4.x — the replicated
-    # outputs here are replicated by construction (every scalar that crosses
-    # shards goes through lax.psum)
-    sm = shard_map_compat(
+    # the replication checker (check_vma) is off: the scan carry mixes
+    # replicated and sharded leaves, and the replicated outputs here are
+    # replicated by construction (every scalar that crosses shards goes
+    # through lax.psum)
+    sm = jax.shard_map(
         window_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     return jax.jit(sm, donate_argnums=tuple(range(12)))
@@ -661,7 +663,7 @@ class DistSimulation:
         self._mesh_ctx = contextlib.ExitStack()
         try:
             with self._mesh_ctx:
-                self._mesh_ctx.enter_context(set_mesh_compat(self.mesh))
+                self._mesh_ctx.enter_context(jax.set_mesh(self.mesh))
                 if window is None:
                     self._run_host(n_steps, diagnostics_every)
                 else:
@@ -695,14 +697,33 @@ class DistSimulation:
         self._pending_presort = False
         self._pending_resume = False
         vec = no_fault_vec() if fault_vec is None else fault_vec
+        # enter every window with the shardings the window returns, so the
+        # first window (host-built arrays) and later ones (the previous
+        # window's outputs) share one compiled program
+        state = self._on_mesh((
+            self.fields, self.pos, self.u, self.w, self.alive, self.slots, self.pslot,
+            self.slab_d, self.slab_valid, self.mid_pos, self.mid_u,
+        ))
+        pstate = jax.device_put(self.policy_state, NamedSharding(self.mesh, P()))
         (self.fields, self.pos, self.u, self.w, self.alive, self.slots, self.pslot,
          self.slab_d, self.slab_valid, self.mid_pos, self.mid_u,
          self.policy_state, bundle) = fn(
-            self.fields, self.pos, self.u, self.w, self.alive, self.slots, self.pslot,
-            self.slab_d, self.slab_valid, self.mid_pos, self.mid_u, self.policy_state,
+            *state, pstate,
             jnp.int32(k), presort, resume, jnp.int32(self._host_step), armed, vec,
         )
         return _fetch_bundle(bundle)
+
+    def _on_mesh(self, arrays):
+        """Place shard-major arrays (leading (sx, sy) shard axes, or a
+        global field grid) on the mesh exactly as the window's shard_map
+        splits them — a no-op for arrays already placed so."""
+        cfg = self.config
+
+        def put(a):
+            spec = P(cfg.x_axes, cfg.y_axes, *([None] * (a.ndim - 2)))
+            return jax.device_put(a, NamedSharding(self.mesh, spec))
+
+        return jax.tree.map(put, arrays)
 
     def _consume_bundle(self, host: dict, diagnostics_every: int) -> int:
         """Commit a successful (or growth-halted) window's accounting."""
@@ -1044,7 +1065,7 @@ class DistSimulation:
         self._prewarm_dispatch()
         if self._mesh_ctx is not None:
             self._mesh_ctx.close()
-            self._mesh_ctx.enter_context(set_mesh_compat(self.mesh))
+            self._mesh_ctx.enter_context(jax.set_mesh(self.mesh))
 
     # -- protocol state view + checkpointing -------------------------------
 

@@ -69,19 +69,14 @@ from repro.pic.maxwell import curl_b_padded, curl_e_padded
 from repro.pic.plasma import ParticleState
 from repro.pic.pusher import advance_positions, boris_push, lorentz_gamma
 from repro.core.shape_functions import max_guard
-from repro.compat import axis_size_compat, shard_map_compat
 
 
 # ---------------------------------------------------------------------------
 # collective helpers (inside shard_map)
 # ---------------------------------------------------------------------------
 
-def _axis_size(axis_name):
-    return axis_size_compat(axis_name)
-
-
 def _ring(axis_name, shift):
-    n = axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if shift == +1:
         return [(i, (i + 1) % n) for i in range(n)]
     return [((i + 1) % n, i) for i in range(n)]
@@ -722,7 +717,7 @@ def make_dist_step(mesh, cfg: DistConfig):
         return (fields, ex(pos), ex(u), ex(w), ex(alive), ex(slots), ex(pslot),
                 ex(slab_d), ex(slab_valid), stats)
 
-    sm = shard_map_compat(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return jax.jit(sm)
 
 
@@ -752,7 +747,7 @@ def make_dist_sort(mesh, cfg: DistConfig):
         return (ex(pos), ex(u), ex(w), ex(alive), ex(slots), ex(pslot),
                 ex(slab_d), ex(slab_valid), psum_all(overflow, cfg))
 
-    sm = shard_map_compat(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return jax.jit(sm)
 
 

@@ -16,7 +16,6 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.data import DataConfig, global_batch_at  # noqa: E402
-from repro.compat import make_mesh_compat, set_mesh_compat, shard_map_compat  # noqa: E402
 from repro.distributed.compression import compressed_psum_grads, exact_pmean_grads, zeros_like_residual  # noqa: E402
 from repro.distributed.pipeline import pipeline_forward  # noqa: E402
 from repro.distributed.sharding import Rules, train_rules, tree_specs, use_rules  # noqa: E402
@@ -41,7 +40,7 @@ def check_sharded_train_step():
     # single device reference
     ref_state, ref_m = jax.jit(step)(state, global_batch_at(0, DATA))
 
-    mesh = make_mesh_compat((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = Rules(train_rules(multi_pod=False), mesh)
     axes = {"params": param_axes(CFG)}
     pspecs = tree_specs(axes["params"], rules)
@@ -61,7 +60,7 @@ def check_sharded_train_step():
     batch = jax.tree.map(
         lambda x: jax.device_put(x, NamedSharding(mesh, P(("data",), None))), global_batch_at(0, DATA)
     )
-    with set_mesh_compat(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         got_state, got_m = jax.jit(step)(sh_state, batch)
         jax.block_until_ready(got_state)
 
@@ -82,7 +81,7 @@ def check_pipeline_parallel():
         return jnp.tanh(x @ wi)
 
     x = jax.random.normal(key, (n_micro, mb, d))
-    mesh = make_mesh_compat((n_stages,), ("pipe",))
+    mesh = jax.make_mesh((n_stages,), ("pipe",), axis_types=(jax.sharding.AxisType.Auto,) * 1)
     got = pipeline_forward(w, x, stage_fn, mesh=mesh)
 
     ref = x
@@ -95,7 +94,7 @@ def check_pipeline_parallel():
 def check_compressed_dp():
     from repro.optim import adamw_init, adamw_update
 
-    mesh = make_mesh_compat((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,) * 1)
     k = jax.random.PRNGKey(2)
     w0 = jax.random.normal(k, (16, 16)) * 0.3
 
@@ -116,7 +115,7 @@ def check_compressed_dp():
                     g = exact_pmean_grads(g, "data")
                 return g, res
 
-            g, res = shard_map_compat(
+            g, res = jax.shard_map(
                 body, mesh=mesh, in_specs=(P(), P(), P("data")), out_specs=(P(), P()), check_vma=False
             )(w, res, x_shard)
             w, opt, _ = adamw_update(g, opt, w, AdamWConfig(lr=1e-2, weight_decay=0.0))

@@ -30,7 +30,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.compat import set_mesh_compat  # noqa: E402
 from repro.pic import GridSpec  # noqa: E402
 from repro.pic.distributed import DistConfig, build_local_bins, make_dist_step, partition_particles  # noqa: E402
 from repro.pic.dist_simulation import make_pic_mesh  # noqa: E402
@@ -63,7 +62,7 @@ def main() -> None:
         return (p[..., 0] >= 0) & (p[..., 0] < local.shape[0]) & (p[..., 1] >= 0) & (p[..., 1] < local.shape[1])
 
     landed_at = None
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         for n in range(1, 5):
             ex_before = np.asarray(fields[0]).sum(dtype=np.float64)
             fields, ppos, pu, pw, palive, slots, pslot, slab_d, slab_valid, stats = step(

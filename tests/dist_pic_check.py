@@ -17,7 +17,6 @@ import numpy as np  # noqa: E402
 
 from repro.pic import FieldState, GridSpec, PICConfig, Simulation, uniform_plasma  # noqa: E402
 from repro.pic.distributed import DistConfig, build_local_bins, make_dist_step, partition_particles  # noqa: E402
-from repro.compat import set_mesh_compat  # noqa: E402
 
 
 def main() -> None:
@@ -41,7 +40,7 @@ def main() -> None:
 
     fields = tuple(jnp.zeros(grid.shape, jnp.float32) for _ in range(6))
     step = make_dist_step(mesh, dcfg)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(steps):
             fields, pos, u, w, alive, slots, pslot, slab_d, slab_valid, stats = step(
                 fields, pos, u, w, alive, slots, pslot, slab_d, slab_valid

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh_compat, shard_map_compat
 from repro.distributed.compression import (
     MIG_ROW_BYTES_COMPRESSED,
     MIG_ROW_BYTES_EXACT,
@@ -85,14 +84,14 @@ def _psum_one(grads, residuals, compress: bool):
     """Run one (possibly compressed) gradient all-reduce on a 1-device mesh
     (psum/pmax degenerate to identity; the quantize/residual algebra is
     exercised unchanged)."""
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,) * 1)
 
     def body(g, r):
         if compress:
             return compressed_psum_grads(g, r, "data")
         return exact_pmean_grads(g, "data"), r
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )(grads, residuals)
 

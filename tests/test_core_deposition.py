@@ -98,7 +98,7 @@ def test_matrix_vs_float64_oracle(order):
     layout, _ = build_bins(cells, jnp.ones(pos.shape[0], bool), n_cells=n_cells, capacity=cap)
     mx = deposit_matrix(pos, values, layout, grid_shape=GRID, order=order)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ref64 = deposit_scatter(
             jnp.asarray(np.asarray(pos), jnp.float64),
             jnp.asarray(np.asarray(values), jnp.float64),

@@ -163,6 +163,22 @@ def test_auto_benchmarks_once_then_hits_cache():
     assert dispatch.counters["memo_hit"] == hits + 1
 
 
+def test_describe_reports_offered_and_measured_without_benchmarking():
+    kw = dict(order=1, grid_shape=(4, 4, 4), capacity=4)
+    before = dispatch.describe(["deposit_fused", "gather_fused"], **kw)
+    assert before == {
+        "deposit_fused": {"offered": ["pallas_reduced", "pallas", "xla"], "timings_us": None},
+        "gather_fused": {"offered": ["pallas", "xla"], "timings_us": None},
+    }
+    assert dispatch.counters["benchmark"] == 0
+    dispatch.prewarm(["deposit_fused"], **kw)
+    after = dispatch.describe(["deposit_fused"], **kw)["deposit_fused"]
+    entry = next(iter(json.load(open(dispatch.cache_path()))["entries"].values()))
+    assert after["timings_us"] == entry["timings_us"]
+    assert set(after["timings_us"]) == {"xla", "pallas", "pallas_reduced"}
+    assert dispatch.counters["benchmark"] == 1
+
+
 def test_cache_key_distinguishes_shapes():
     a = dict(order=1, grid_shape=(4, 4, 4), capacity=4)
     b = dict(order=2, grid_shape=(4, 4, 4), capacity=4)

@@ -104,7 +104,7 @@ def test_fused_vs_float64_scatter_oracle(order):
     layout, _ = make_binned(pos, grid)
     fused = deposit_current_matrix_fused(pos, vel, qw, layout, grid_shape=grid, order=order)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for comp in range(3):
             ref64 = deposit_scatter(
                 jnp.asarray(np.asarray(pos), jnp.float64),

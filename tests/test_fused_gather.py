@@ -43,12 +43,15 @@ def _ignore_deprecation(fn):
 Simulation = _ignore_deprecation(Simulation)
 
 
-def make_workload(n, grid_shape, *, seed=0, capacity=None, n_dead=0):
+def make_workload(n, grid_shape, *, seed=0, capacity=None, n_dead=0, n_crowded=0):
     """Particles (some dead), six random field components, bins + slab.
-    A small ``capacity`` forces unslotted overflow particles."""
+    The first ``n_crowded`` particles all sit in cell (0, 0, 0), so a
+    ``capacity`` below that count always leaves unslotted overflow
+    particles."""
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     dims = jnp.asarray(grid_shape, jnp.float32)
-    pos = jax.random.uniform(k1, (n, 3)) * dims
+    unit = jax.random.uniform(k1, (n, 3))
+    pos = jnp.where(jnp.arange(n)[:, None] < n_crowded, unit, unit * dims)
     alive = jnp.arange(n) >= n_dead
     cells = cell_index(pos, grid_shape)
     n_cells = int(np.prod(grid_shape))
@@ -105,7 +108,7 @@ def test_fused_gather_matches_scatter_oracle(order, grid_shape):
 def test_fused_gather_matches_six_call_path(order):
     """Fused == the six independent gather_matrix calls it replaces,
     including unslotted OVERFLOW particles (capacity too small)."""
-    wl = make_workload(400, GRID, capacity=8)
+    wl = make_workload(400, GRID, capacity=8, n_crowded=12)
     assert wl["overflow"] > 0, "workload must include unslotted overflow particles"
     e_p, b_p = gather_fields_fused(
         wl["slab"], _padded(wl["fields"], order), wl["layout"],

@@ -157,7 +157,7 @@ def test_run_window_diff_rejects_pallas_backends():
 def test_grad_matches_central_fd_per_order(order):
     """AD through a short LWFA window matches central finite differences in
     f64 at every deposition order the matrix formulation supports."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = _lwfa(order=order)
         loss_fn, params = make_objective(
             spec, learn=("laser.a0", "density"), steps=4,
@@ -179,7 +179,7 @@ def test_grad_matches_central_fd_per_order(order):
 def test_grad_matches_central_fd_20_step_lwfa():
     """Acceptance: jax.grad through a >=20-step windowed LWFA run matches
     central FD on EVERY learned parameter (f64, rtol <= 1e-3)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = _lwfa()
         learn = tuple(sorted(LEARNABLE))
         loss_fn, params = make_objective(
