@@ -1,0 +1,9 @@
+"""Device milliseconds per step in the push layer, attributed from the
+trace by bench/layers/push.json."""
+
+
+def read(ctx):
+    seconds = ctx.trace.layer_s.get("push", 0.0)
+    if seconds <= 0 or ctx.steps <= 0:
+        return None
+    return 1e3 * seconds / ctx.steps
