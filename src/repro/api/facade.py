@@ -498,6 +498,10 @@ def save_simulation(sim, path: str) -> None:
     scalars = {
         "sorts": sim.sorts,
         "rebuilds": sim.rebuilds,
+        "moved": sim.moved,
+        "particle_steps": sim.particle_steps,
+        "ranked": sim.ranked,
+        "sort_reasons": dict(sim.sort_reasons),
         "host_step": sim._host_step,
         "capacity": sim.config.capacity,
         "host_policy": _host_policy_scalars(sim),
@@ -611,6 +615,11 @@ def restore_simulation(sim, path: str) -> None:
     sim.policy_state = restored["policy_state"]
     sim.sorts = scal["sorts"]
     sim.rebuilds = scal["rebuilds"]
+    # sorter counters (absent from checkpoints that predate them)
+    sim.moved = int(scal.get("moved", 0))
+    sim.particle_steps = int(scal.get("particle_steps", 0))
+    sim.ranked = int(scal.get("ranked", 0))
+    sim.sort_reasons = dict(scal.get("sort_reasons", {}))
     sim._host_step = scal["host_step"]
     sim.history = list(scal["history"])
     sim.growths = dict(scal.get("growths", sim.growths))

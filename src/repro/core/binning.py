@@ -179,8 +179,12 @@ def build_bins(cell_ids, alive, *, n_cells: int, capacity: int):
     key = jnp.where(alive, cell_ids, n_cells)  # dead -> sentinel bin
     order = jnp.argsort(key, stable=True)
     sorted_key = key[order]
-    # rank within cell = position - first position of this cell id
-    first = jnp.searchsorted(sorted_key, sorted_key, side="left")
+    # rank within cell = position - first position of this cell id. The
+    # search is traced without its jit wrapper: the GPMA rank makes the same
+    # jitted call at the same shapes, JAX lowers one loop body for both, and
+    # the TPU compiler then labels both copies with the rank's scope
+    # (docs/sim_loop.md, "Profiling a run"). The ops are the same.
+    first = jnp.searchsorted.__wrapped__(sorted_key, sorted_key, side="left")
     rank = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
 
     in_range = (sorted_key < n_cells) & (rank < capacity)

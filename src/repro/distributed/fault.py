@@ -33,6 +33,7 @@ import time
 from typing import Callable
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.health import (
     HALT_INVARIANT,
@@ -350,61 +351,73 @@ def run_supervised_windows(sim, n_steps: int, diagnostics_every: int,
                 k = min(window, target - sim._host_step)
                 if retry_target:
                     k = min(k, retry_target)
-                if inj is not None:
-                    inj.maybe_crash(sim._host_step, k)
-                fault_vec = inj.window_vec(sim._host_step, k) if inj is not None else None
-                snap = sim._take_snapshot() if health is not None else None
-                host = sim._enter_window(k, window, diagnostics_every, fault_vec)
-                code = int(host.get("halt_code", 0))
-
-                if code in (HALT_NONFINITE, HALT_INVARIANT):
-                    sim._restore_snapshot(snap)
-                    name = HALT_NAMES[code]
-                    sim.halts[name] = sim.halts.get(name, 0) + 1
+                # host spans on the profiler's clock, each tagged with the
+                # window's start step and live steps (docs/sim_loop.md)
+                tag = {"step": sim._host_step, "k": k}
+                with TraceAnnotation("pic.window", **tag):
                     if inj is not None:
-                        inj.note_halt(code, int(host.get("halt_step", -1)))
-                    sim.retries += 1
-                    sim._remedy_level += 1
-                    level = sim._remedy_level
-                    exhausted = level > max_retries
-                    if not exhausted and level >= 3:
-                        # last rung: demote the kernel backend one step down
-                        # the dispatcher's priority ladder; exhausted when
-                        # already on the most conservative backend
-                        exhausted = not sim._demote_backend()
-                    if exhausted:
-                        raise SimulationHealthError(
-                            halt=name,
-                            step=int(host.get("halt_step", -1)),
-                            invariant=INVARIANT_NAMES[int(host.get("halt_inv", 0))],
-                            measured=float(host.get("halt_measured", float("nan"))),
-                            reference=float(host.get("halt_reference", float("nan"))),
-                            retries=sim.retries,
+                        inj.maybe_crash(sim._host_step, k)
+                    fault_vec = inj.window_vec(sim._host_step, k) if inj is not None else None
+                    snap = None
+                    if health is not None:
+                        with TraceAnnotation("pic.window.snapshot", **tag):
+                            snap = sim._take_snapshot()
+                    host = sim._enter_window(k, window, diagnostics_every, fault_vec)
+                    code = int(host.get("halt_code", 0))
+
+                    if code in (HALT_NONFINITE, HALT_INVARIANT):
+                        sim._restore_snapshot(snap)
+                        name = HALT_NAMES[code]
+                        sim.halts[name] = sim.halts.get(name, 0) + 1
+                        if inj is not None:
+                            inj.note_halt(code, int(host.get("halt_step", -1)))
+                        sim.retries += 1
+                        sim._remedy_level += 1
+                        level = sim._remedy_level
+                        exhausted = level > max_retries
+                        if not exhausted and level >= 3:
+                            # last rung: demote the kernel backend one step down
+                            # the dispatcher's priority ladder; exhausted when
+                            # already on the most conservative backend
+                            with TraceAnnotation("pic.window.remedy", **tag):
+                                exhausted = not sim._demote_backend()
+                        if exhausted:
+                            raise SimulationHealthError(
+                                halt=name,
+                                step=int(host.get("halt_step", -1)),
+                                invariant=INVARIANT_NAMES[int(host.get("halt_inv", 0))],
+                                measured=float(host.get("halt_measured", float("nan"))),
+                                reference=float(host.get("halt_reference", float("nan"))),
+                                retries=sim.retries,
+                            )
+                        if level == 1:
+                            retry_target = max(1, k // 2)
+                        elif level == 2:
+                            with TraceAnnotation("pic.window.remedy", **tag):
+                                sim._remedy_sort()
+                        log.warning(
+                            "health halt %s at step %s: rollback, remediation level %d",
+                            name, host.get("halt_step"), level,
                         )
-                    if level == 1:
-                        retry_target = max(1, k // 2)
-                    elif level == 2:
-                        sim._remedy_sort()
-                    log.warning(
-                        "health halt %s at step %s: rollback, remediation level %d",
-                        name, host.get("halt_step"), level,
-                    )
-                    continue
+                        continue
 
-                n_done = sim._consume_bundle(host, diagnostics_every)
-                sim.discarded_steps += int(host.get("n_discarded", 0))
-                sim._remedy_level = 0
-                retry_target = 0
-                if code:
-                    name = HALT_NAMES[code]
-                    sim.halts[name] = sim.halts.get(name, 0) + 1
-                    if inj is not None:
-                        inj.note_halt(code, int(host.get("halt_step", -1)))
-                    sim._handle_halt(code, host)
-                elif n_done < k:
-                    raise RuntimeError("windowed driver made no progress without a halt")
-                if ckpt is not None:
-                    ckpt.maybe_save(sim._host_step)
+                    with TraceAnnotation("pic.window.consume", **tag):
+                        n_done = sim._consume_bundle(host, diagnostics_every)
+                    sim.discarded_steps += int(host.get("n_discarded", 0))
+                    sim._remedy_level = 0
+                    retry_target = 0
+                    if code:
+                        name = HALT_NAMES[code]
+                        sim.halts[name] = sim.halts.get(name, 0) + 1
+                        if inj is not None:
+                            inj.note_halt(code, int(host.get("halt_step", -1)))
+                        with TraceAnnotation("pic.window.halt", **tag):
+                            sim._handle_halt(code, host)
+                    elif n_done < k:
+                        raise RuntimeError("windowed driver made no progress without a halt")
+                    if ckpt is not None:
+                        with TraceAnnotation("pic.window.checkpoint", **tag):
+                            ckpt.maybe_save(sim._host_step)
             break
         except SimulationHealthError:
             raise

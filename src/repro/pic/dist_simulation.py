@@ -80,7 +80,7 @@ from repro.core.health import (  # noqa: F401
     classify_health,
     nonfinite_count,
 )
-from repro.core.resort_policy import REASON_OVERFLOW
+from repro.core.resort_policy import REASON_NAMES, REASON_OVERFLOW
 from repro.distributed.sharding import plan_balanced_split
 from repro.distributed.fault import (
     PICFaultInjector,
@@ -106,7 +106,14 @@ from repro.pic.distributed import (
 from repro.pic.grid import FieldState, GridSpec
 from repro.pic.plasma import ParticleState
 from repro.pic.pusher import lorentz_gamma
-from repro.pic.simulation import UNSET, _DEPRECATION_MSG, consume_window_bundle, resolve_run_args
+from repro.pic.simulation import (
+    UNSET,
+    _DEPRECATION_MSG,
+    add_counts,
+    commit_window_counts,
+    consume_window_bundle,
+    resolve_run_args,
+)
 
 # Module-level alias so tests can monkeypatch and count the (single) per-
 # window device->host transfer, mirroring pic.simulation._fetch_bundle.
@@ -260,15 +267,16 @@ def make_dist_window(mesh, cfg: DistConfig, policy: SortPolicyConfig, n_steps: i
             # in-graph re-sort policy over the psum-reduced stats: the reduced
             # scalars are replicated across shards, so the decision (and hence
             # the lax.cond branch below) is taken uniformly
-            mandatory = stats["n_overflow"] > 0
-            do_pol, reason_pol, pstate_rec = policy_update(
-                pstate, policy,
-                n_moved=stats["n_moved"], n_alive=stats["n_alive"],
-                n_empty=stats["n_empty"], n_slots=n_slots_total,
-            )
-            do_pol = do_pol & ~mandatory
-            do_sort = mandatory | do_pol
-            reason = jnp.where(mandatory, jnp.int32(REASON_OVERFLOW), reason_pol).astype(jnp.int32)
+            with jax.named_scope("pic.policy"):
+                mandatory = stats["n_overflow"] > 0
+                do_pol, reason_pol, pstate_rec = policy_update(
+                    pstate, policy,
+                    n_moved=stats["n_moved"], n_alive=stats["n_alive"],
+                    n_empty=stats["n_empty"], n_slots=n_slots_total,
+                )
+                do_pol = do_pol & ~mandatory
+                do_sort = mandatory | do_pol
+                reason = jnp.where(mandatory, jnp.int32(REASON_OVERFLOW), reason_pol).astype(jnp.int32)
 
             # per-shard global sort under lax.cond — purely local work (attribute
             # permutation + bin/slab rebuild), so no collective sits inside the
@@ -280,13 +288,15 @@ def make_dist_window(mesh, cfg: DistConfig, policy: SortPolicyConfig, n_steps: i
                 pos, u, w, alive = args
                 return pos, u, w, alive, nslots, npslot, nslab_d, nslab_valid, jnp.zeros((), jnp.int32)
 
-            npos, nu, nw, nalive, nslots, npslot, nslab_d, nslab_valid, overflow_local = lax.cond(
-                do_sort, sort_branch, no_sort, (npos, nu, nw, nalive)
-            )
-            overflow_after = psum_all(overflow_local, cfg)
-            pstate_new = jax.tree.map(
-                lambda r, n: jnp.where(do_sort, r, n), policy_reset(), pstate_rec
-            )
+            with jax.named_scope("pic.global_sort"):
+                npos, nu, nw, nalive, nslots, npslot, nslab_d, nslab_valid, overflow_local = lax.cond(
+                    do_sort, sort_branch, no_sort, (npos, nu, nw, nalive)
+                )
+                overflow_after = psum_all(overflow_local, cfg)
+            with jax.named_scope("pic.policy"):
+                pstate_new = jax.tree.map(
+                    lambda r, n: jnp.where(do_sort, r, n), policy_reset(), pstate_rec
+                )
 
             # energies of the candidate post-step state: the sentinel checks
             # them, and the per-step diagnostics report them (identical to
@@ -397,6 +407,7 @@ def make_dist_window(mesh, cfg: DistConfig, policy: SortPolicyConfig, n_steps: i
                 "reason": jnp.where(counted, reason, 0).astype(jnp.int32),
                 "n_moved": jnp.where(counted, stats["n_moved"], 0).astype(jnp.int32),
                 "n_alive": jnp.where(counted, stats["n_alive"], 0).astype(jnp.int32),
+                "n_ranked": jnp.where(counted, stats["n_ranked"], 0).astype(jnp.int32),
                 "mig_send_overflow": jnp.where(counted, stats["mig_send_overflow"], 0).astype(jnp.int32),
                 "mig_recv_dropped": jnp.where(executed, stats["mig_recv_dropped"], 0).astype(jnp.int32),
                 "n_unmigrated": jnp.where(counted, stats["n_unmigrated"], 0).astype(jnp.int32),
@@ -572,6 +583,11 @@ class DistSimulation:
         self.policy_state = policy_init()
         self.sorts = 0
         self.rebuilds = 0
+        # sorter counters (docs/sim_loop.md, "Profiling a run"; add_counts)
+        self.moved = 0
+        self.particle_steps = 0
+        self.ranked = 0
+        self.sort_reasons: dict[str, int] = {}
         self._pending_presort = False  # capacity-growth re-entry flag
         self._pending_resume = False   # recv-drop replay re-entry flag
         self.growths = {"capacity": 0, "mig_cap": 0, "n_local": 0, "rebalance": 0}
@@ -689,6 +705,7 @@ class DistSimulation:
         program) and fetch its bundle — the single device->host sync of the
         window. Consumes (and clears) the pending presort/resume re-entry
         flags."""
+        tag = {"step": self._host_step, "k": k}
         fn = self._window_fn(window, bool(diagnostics_every), self._health,
                              fault_vec is not None)
         presort = jnp.int32(1 if self._pending_presort else 0)
@@ -705,13 +722,15 @@ class DistSimulation:
             self.slab_d, self.slab_valid, self.mid_pos, self.mid_u,
         ))
         pstate = jax.device_put(self.policy_state, NamedSharding(self.mesh, P()))
-        (self.fields, self.pos, self.u, self.w, self.alive, self.slots, self.pslot,
-         self.slab_d, self.slab_valid, self.mid_pos, self.mid_u,
-         self.policy_state, bundle) = fn(
-            *state, pstate,
-            jnp.int32(k), presort, resume, jnp.int32(self._host_step), armed, vec,
-        )
-        return _fetch_bundle(bundle)
+        with jax.profiler.TraceAnnotation("pic.window.launch", **tag):
+            (self.fields, self.pos, self.u, self.w, self.alive, self.slots, self.pslot,
+             self.slab_d, self.slab_valid, self.mid_pos, self.mid_u,
+             self.policy_state, bundle) = fn(
+                *state, pstate,
+                jnp.int32(k), presort, resume, jnp.int32(self._host_step), armed, vec,
+            )
+        with jax.profiler.TraceAnnotation("pic.window.fetch", **tag):
+            return _fetch_bundle(bundle)
 
     def _on_mesh(self, arrays):
         """Place shard-major arrays (leading (sx, sy) shard axes, or a
@@ -727,12 +746,8 @@ class DistSimulation:
 
     def _consume_bundle(self, host: dict, diagnostics_every: int) -> int:
         """Commit a successful (or growth-halted) window's accounting."""
-        n_done, n_sorts, n_rebuilds = consume_window_bundle(
-            host, self._host_step, diagnostics_every, self.history
-        )
-        self.sorts += n_sorts
-        self.rebuilds += n_rebuilds
-        self._host_step += n_done
+        counts = consume_window_bundle(host, self._host_step, diagnostics_every, self.history)
+        commit_window_counts(self, counts)
         # communication accounting: the per-step arrays are zero-masked on
         # uncounted steps, so plain sums/maxima commit exactly the kept work
         per = host["per_step"]
@@ -744,7 +759,7 @@ class DistSimulation:
         if mask.any():
             ratio = float(np.max(peak[mask] * (self.sx * self.sy) / n_alive[mask]))
             self.comm_stats["max_imbalance"] = max(self.comm_stats["max_imbalance"], ratio)
-        return n_done
+        return counts.n_done
 
     def _take_snapshot(self):
         """Deep-copy the window carry (the windowed call donates its
@@ -845,6 +860,8 @@ class DistSimulation:
             # per-key int() would cost a blocking round-trip each)
             stats = {k: int(v) for k, v in jax.device_get(stats).items()}
             self._host_step += 1
+            add_counts(self, moved=stats["n_moved"], particle_steps=stats["n_alive"],
+                       ranked=stats["n_ranked"])
             self.comm_stats["n_migrated"] += stats["n_migrated"]
             self.comm_stats["mig_payload_bytes"] += stats["mig_payload_bytes"]
             if stats["n_alive"]:
@@ -863,17 +880,19 @@ class DistSimulation:
             if stats["n_overflow"] > 0:
                 self._dist_sort()
                 self.rebuilds += 1
+                add_counts(self, sort_reasons={REASON_NAMES[REASON_OVERFLOW]: 1})
                 self.policy.reset()
             else:
                 dtep = time.perf_counter() - t0
                 perf = float(stats["n_alive"]) / max(dtep, 1e-9)
                 self.policy.record_step(rebuilt=False, perf=perf)
-                do, _reason = self.policy.should_sort(
+                do, reason = self.policy.should_sort(
                     empty_ratio=stats["n_empty"] / max(n_slots_total, 1)
                 )
                 if do:
                     self._dist_sort()
                     self.sorts += 1
+                    add_counts(self, sort_reasons={reason: 1})
                     self.policy.reset()
             if diagnostics_every and self._host_step % diagnostics_every == 0:
                 self.history.append(self.diagnostics())

@@ -471,137 +471,152 @@ def dist_pic_step_local(fields, pos, u, w, alive, slots, particle_slot, slab_d, 
     resident = alive & in_domain(pos, shape)
 
     # 1. halo-extended fields + gather
-    pe = [_extend_all(f, g, cfg) for f in (ex, ey, ez)]
-    pb = [_extend_all(f, g, cfg) for f in (bx, by, bz)]
-    if cfg.gather == "matrix":
-        # fused six-component pass over the carried slab (one staging, six
-        # shared weight sets, one slot-map scatter-back); the contraction
-        # backend resolves through the kernel dispatcher
-        e_p, b_p = gather_fields_fused(
-            BinSlab(d=slab_d, valid=slab_valid), tuple(pe) + tuple(pb), layout,
-            grid_shape=shape, order=cfg.order, backend=cfg.backend,
-        )
-    else:  # matrix_unfused: six-call comparison mode
-        e_p = jnp.stack(
-            [gather_matrix(pos, pe[k], layout, grid_shape=shape, order=cfg.order, stagger=E_STAGGER[k], backend=cfg.backend) for k in range(3)], -1
-        )
-        b_p = jnp.stack(
-            [gather_matrix(pos, pb[k], layout, grid_shape=shape, order=cfg.order, stagger=B_STAGGER[k], backend=cfg.backend) for k in range(3)], -1
-        )
+    with jax.named_scope("pic.halo"):
+        pe = [_extend_all(f, g, cfg) for f in (ex, ey, ez)]
+        pb = [_extend_all(f, g, cfg) for f in (bx, by, bz)]
+    with jax.named_scope("pic.gather"):
+        if cfg.gather == "matrix":
+            # fused six-component pass over the carried slab (one staging, six
+            # shared weight sets, one slot-map scatter-back); the contraction
+            # backend resolves through the kernel dispatcher
+            e_p, b_p = gather_fields_fused(
+                BinSlab(d=slab_d, valid=slab_valid), tuple(pe) + tuple(pb), layout,
+                grid_shape=shape, order=cfg.order, backend=cfg.backend,
+            )
+        else:  # matrix_unfused: six-call comparison mode
+            e_p = jnp.stack(
+                [gather_matrix(pos, pe[k], layout, grid_shape=shape, order=cfg.order, stagger=E_STAGGER[k], backend=cfg.backend) for k in range(3)], -1
+            )
+            b_p = jnp.stack(
+                [gather_matrix(pos, pb[k], layout, grid_shape=shape, order=cfg.order, stagger=B_STAGGER[k], backend=cfg.backend) for k in range(3)], -1
+            )
 
     # 2. push (positions NOT wrapped: out-of-range triggers migration);
     # frozen out-of-domain particles keep position AND momentum so they
     # retry migration with the same coordinates
-    u_new = jnp.where(resident[:, None], boris_push(u, e_p, b_p, cfg.charge / cfg.mass, cfg.dt), u)
-    pos_new = jnp.where(resident[:, None], advance_positions(pos, u_new, cfg.dt, cfg.local_grid.dx), pos)
+    with jax.named_scope("pic.push"):
+        u_new = jnp.where(resident[:, None], boris_push(u, e_p, b_p, cfg.charge / cfg.mass, cfg.dt), u)
+        pos_new = jnp.where(resident[:, None], advance_positions(pos, u_new, cfg.dt, cfg.local_grid.dx), pos)
 
-    # 3. migration (x then y; z wraps locally)
-    pos_new = pos_new.at[:, 2].set(jnp.mod(pos_new[:, 2], shape[2]))
-    if use_mid is not None:
-        pos_new = jnp.where(use_mid, mid_pos, pos_new)
-        u_new = jnp.where(use_mid, mid_u, u_new)
+        # 3. migration (x then y; z wraps locally)
+        pos_new = pos_new.at[:, 2].set(jnp.mod(pos_new[:, 2], shape[2]))
+        if use_mid is not None:
+            pos_new = jnp.where(use_mid, mid_pos, pos_new)
+            u_new = jnp.where(use_mid, mid_u, u_new)
     # post-push / pre-migration snapshot (returned for the window carry)
     mid_pos_out, mid_u_out = pos_new, u_new
     mig_send_overflow = jnp.int32(0)
     mig_recv_dropped = jnp.int32(0)
     arrived = jnp.zeros_like(alive)
     compress = cfg.comm.compress_migration
-    for ax_name in cfg.x_axes:
-        pos_new, u_new, w, alive, of, dr, ins = migrate_axis(
-            pos_new, u_new, w, alive, coord=0, extent=shape[0], axis_name=ax_name, mig_cap=cfg.mig_cap,
-            local_shape=shape, compress=compress,
-        )
-        mig_send_overflow += of
-        mig_recv_dropped += dr
-        arrived |= ins
-    for ax_name in cfg.y_axes:
-        pos_new, u_new, w, alive, of, dr, ins = migrate_axis(
-            pos_new, u_new, w, alive, coord=1, extent=shape[1], axis_name=ax_name, mig_cap=cfg.mig_cap,
-            local_shape=shape, compress=compress,
-        )
-        mig_send_overflow += of
-        mig_recv_dropped += dr
-        arrived |= ins
+    with jax.named_scope("pic.migrate"):
+        for ax_name in cfg.x_axes:
+            pos_new, u_new, w, alive, of, dr, ins = migrate_axis(
+                pos_new, u_new, w, alive, coord=0, extent=shape[0], axis_name=ax_name, mig_cap=cfg.mig_cap,
+                local_shape=shape, compress=compress,
+            )
+            mig_send_overflow += of
+            mig_recv_dropped += dr
+            arrived |= ins
+        for ax_name in cfg.y_axes:
+            pos_new, u_new, w, alive, of, dr, ins = migrate_axis(
+                pos_new, u_new, w, alive, coord=1, extent=shape[1], axis_name=ax_name, mig_cap=cfg.mig_cap,
+                local_shape=shape, compress=compress,
+            )
+            mig_send_overflow += of
+            mig_recv_dropped += dr
+            arrived |= ins
 
     # 4. incremental sort on local bins — send-overflow stragglers are kept
     # OUT of the bins (they retry migration next step; binning them would
     # clip their cell index into the boundary cell and corrupt the gather
     # and deposition with out-of-range shape weights)
-    binned = alive & in_domain(pos_new, shape)
-    new_cells = cell_index(pos_new, shape)
-    # churn accounting for migrated-in arrivals: gpma_update counts an
-    # arrival as a move when its (stale or invalid) particle_slot maps a
-    # DIFFERENT cell, but an arrival that reuses a just-departed index whose
-    # stale slot happens to sit in the arrival's own cell looks stationary
-    # to it. A boundary crossing is one move no matter which shard observes
-    # it (the departure side frees the particle as dead, contributing
-    # nothing), so add those invisible arrivals back — keeping the
-    # moved-fraction perf proxy's churn identical to single-device.
-    stale_cell = jnp.where(particle_slot >= 0, particle_slot // cfg.capacity, -1)
-    n_arrived_invisible = jnp.sum(arrived & binned & (new_cells == stale_cell))
-    layout, gstats = gpma_update(layout, new_cells, binned)
-    # ...and arrivals whose first insert hit a FULL bin: gpma only counts a
-    # fresh unslotted insert when it lands, but the crossing happened this
-    # step regardless — count it now. The particle is not recounted while
-    # it WAITS; the eventual landing does count once more (the same bounded
-    # stall-then-land overcount gpma_update documents), but on this driver
-    # the nonzero overflow mandatory-sorts the very same step, so stalled
-    # arrivals never persist into a later gpma landing in practice.
-    n_arrived_invisible = n_arrived_invisible + jnp.sum(
-        arrived & binned & (stale_cell < 0) & (layout.particle_slot < 0)
-    )
+    with jax.named_scope("pic.gpma"):
+        binned = alive & in_domain(pos_new, shape)
+        new_cells = cell_index(pos_new, shape)
+        # churn accounting for migrated-in arrivals: gpma_update counts an
+        # arrival as a move when its (stale or invalid) particle_slot maps a
+        # DIFFERENT cell, but an arrival that reuses a just-departed index whose
+        # stale slot happens to sit in the arrival's own cell looks stationary
+        # to it. A boundary crossing is one move no matter which shard observes
+        # it (the departure side frees the particle as dead, contributing
+        # nothing), so add those invisible arrivals back — keeping the
+        # moved-fraction perf proxy's churn identical to single-device.
+        stale_cell = jnp.where(particle_slot >= 0, particle_slot // cfg.capacity, -1)
+        n_arrived_invisible = jnp.sum(arrived & binned & (new_cells == stale_cell))
+        layout, gstats = gpma_update(layout, new_cells, binned)
+        # ...and arrivals whose first insert hit a FULL bin: gpma only counts a
+        # fresh unslotted insert when it lands, but the crossing happened this
+        # step regardless — count it now. The particle is not recounted while
+        # it WAITS; the eventual landing does count once more (the same bounded
+        # stall-then-land overcount gpma_update documents), but on this driver
+        # the nonzero overflow mandatory-sorts the very same step, so stalled
+        # arrivals never persist into a later gpma landing in practice.
+        n_arrived_invisible = n_arrived_invisible + jnp.sum(
+            arrived & binned & (stale_cell < 0) & (layout.particle_slot < 0)
+        )
 
     # 5-prep: push-derived deposition inputs, computed BEFORE the staging
     # so the fused matrix path can stage positions and q·w·v values through
     # one slot-table gather (binned particles only: the layout already
     # excludes stragglers, qw masking keeps the oracle identical)
-    gamma = lorentz_gamma(u_new)
-    v = u_new / gamma[:, None]
-    qw = cfg.charge * w * binned.astype(w.dtype)
+    with jax.named_scope("pic.push"):
+        gamma = lorentz_gamma(u_new)
+        v = u_new / gamma[:, None]
+        qw = cfg.charge * w * binned.astype(w.dtype)
 
     # 4b. the step's ONE slab staging, consistent with (pos_new, layout):
     # consumed by the fused deposition below and carried for the next
     # step's fused gather (pure-unfused ablation configs carry the input
     # slab through untouched — nothing consumes it). The matrix deposition
     # stages its value slab through the same gather.
-    values = None
-    if cfg.deposition == "matrix":
-        slab, values = bin_slab_staging(pos_new, v, qw, layout, grid_shape=shape)
-    elif cfg.needs_slab:
-        slab = build_bin_slab(pos_new, layout, grid_shape=shape)
-    else:
-        slab = BinSlab(d=slab_d, valid=slab_valid)
+    with jax.named_scope("pic.stage"):
+        values = None
+        if cfg.deposition == "matrix":
+            slab, values = bin_slab_staging(pos_new, v, qw, layout, grid_shape=shape)
+        elif cfg.needs_slab:
+            slab = build_bin_slab(pos_new, layout, grid_shape=shape)
+        else:
+            slab = BinSlab(d=slab_d, valid=slab_valid)
 
-    # 5. deposition + guard reduction
+    # 5. deposition + guard reduction (the reduction is the halo exchange)
     inv_vol = 1.0 / cfg.local_grid.cell_volume
     if cfg.deposition == "matrix":
-        j3 = deposit_current_matrix_fused(
-            pos_new, v, qw, layout, grid_shape=shape, order=cfg.order,
-            backend=cfg.backend, slab=slab, values=values,
-        )
-        j = [_reduce_all(jp, g, cfg) * inv_vol for jp in j3]
+        with jax.named_scope("pic.deposit"):
+            j3 = deposit_current_matrix_fused(
+                pos_new, v, qw, layout, grid_shape=shape, order=cfg.order,
+                backend=cfg.backend, slab=slab, values=values,
+            )
+        with jax.named_scope("pic.halo"):
+            j = [_reduce_all(jp, g, cfg) * inv_vol for jp in j3]
     else:  # matrix_unfused: per-component comparison mode
         j = []
         for k, stagger in enumerate(((True, False, False), (False, True, False), (False, False, True))):
-            jp = deposit_matrix(
-                pos_new, qw * v[:, k], layout, grid_shape=shape, order=cfg.order, stagger=stagger,
-                backend=cfg.backend,
-            )
-            j.append(_reduce_all(jp, g, cfg) * inv_vol)
+            with jax.named_scope("pic.deposit"):
+                jp = deposit_matrix(
+                    pos_new, qw * v[:, k], layout, grid_shape=shape, order=cfg.order, stagger=stagger,
+                    backend=cfg.backend,
+                )
+            with jax.named_scope("pic.halo"):
+                j.append(_reduce_all(jp, g, cfg) * inv_vol)
 
-    # 6. Maxwell (1-cell halos, slice curls), B-E-B leapfrog
+    # 6. Maxwell (1-cell halos, slice curls), B-E-B leapfrog; its halo
+    # extensions run under pic.halo inside pic.maxwell
     def half_b(exc, eyc, ezc, bxc, byc, bzc, dt_half):
-        epad = [_extend_all(f, 1, cfg) for f in (exc, eyc, ezc)]
+        with jax.named_scope("pic.halo"):
+            epad = [_extend_all(f, 1, cfg) for f in (exc, eyc, ezc)]
         cx, cy, cz = curl_e_padded(*epad, 1, shape, cfg.local_grid.dx)
         return bxc - dt_half * cx, byc - dt_half * cy, bzc - dt_half * cz
 
-    bx1, by1, bz1 = half_b(ex, ey, ez, bx, by, bz, 0.5 * cfg.dt)
-    bpad = [_extend_all(f, 1, cfg) for f in (bx1, by1, bz1)]
-    cx, cy, cz = curl_b_padded(*bpad, 1, shape, cfg.local_grid.dx)
-    ex1 = ex + cfg.dt * (cx - j[0])
-    ey1 = ey + cfg.dt * (cy - j[1])
-    ez1 = ez + cfg.dt * (cz - j[2])
-    bx2, by2, bz2 = half_b(ex1, ey1, ez1, bx1, by1, bz1, 0.5 * cfg.dt)
+    with jax.named_scope("pic.maxwell"):
+        bx1, by1, bz1 = half_b(ex, ey, ez, bx, by, bz, 0.5 * cfg.dt)
+        with jax.named_scope("pic.halo"):
+            bpad = [_extend_all(f, 1, cfg) for f in (bx1, by1, bz1)]
+        cx, cy, cz = curl_b_padded(*bpad, 1, shape, cfg.local_grid.dx)
+        ex1 = ex + cfg.dt * (cx - j[0])
+        ey1 = ey + cfg.dt * (cy - j[1])
+        ez1 = ez + cfg.dt * (cz - j[2])
+        bx2, by2, bz2 = half_b(ex1, ey1, ez1, bx1, by1, bz1, 0.5 * cfg.dt)
 
     # per-step communication accounting (comm co-design observability):
     # the migration payload is statically sized — every migrate_axis call
@@ -620,6 +635,7 @@ def dist_pic_step_local(fields, pos, u, w, alive, slots, particle_slot, slab_d, 
         "n_alive": jnp.sum(alive),
         "n_migrated": jnp.sum(arrived).astype(jnp.int32),
         "mig_payload_bytes": jnp.int32(2 * cfg.mig_cap * row_bytes * n_axis_calls),
+        "n_ranked": gstats.n_ranked,
     }
     # global sums for the resort policy (host- or in-graph)
     for k in list(stats):
@@ -648,7 +664,7 @@ def pmax_all(value, cfg: DistConfig):
 STAT_KEYS = (
     "n_moved", "n_overflow", "n_empty", "mig_send_overflow",
     "mig_recv_dropped", "n_unmigrated", "n_alive",
-    "n_migrated", "mig_payload_bytes", "max_shard_alive",
+    "n_migrated", "mig_payload_bytes", "max_shard_alive", "n_ranked",
 )
 
 
@@ -664,15 +680,16 @@ def dist_global_sort_device(pos, u, w, alive, cfg: DistConfig):
     alive flag — they retry migration on the next step.
     """
     shape = cfg.local_grid.shape
-    binned = alive & in_domain(pos, shape)
-    perm = sort_permutation(cell_index(pos, shape), binned)
-    pos, u, w, alive = pos[perm], u[perm], w[perm], alive[perm]
-    binned = alive & in_domain(pos, shape)
-    layout, overflow = build_bins(
-        cell_index(pos, shape), binned, n_cells=cfg.local_grid.n_cells, capacity=cfg.capacity
-    )
-    slab = build_bin_slab(pos, layout, grid_shape=shape)
-    return pos, u, w, alive, layout.slots, layout.particle_slot, slab.d, slab.valid, overflow.astype(jnp.int32)
+    with jax.named_scope("pic.global_sort"):
+        binned = alive & in_domain(pos, shape)
+        perm = sort_permutation(cell_index(pos, shape), binned)
+        pos, u, w, alive = pos[perm], u[perm], w[perm], alive[perm]
+        binned = alive & in_domain(pos, shape)
+        layout, overflow = build_bins(
+            cell_index(pos, shape), binned, n_cells=cfg.local_grid.n_cells, capacity=cfg.capacity
+        )
+        slab = build_bin_slab(pos, layout, grid_shape=shape)
+        return pos, u, w, alive, layout.slots, layout.particle_slot, slab.d, slab.valid, overflow.astype(jnp.int32)
 
 
 def make_dist_step(mesh, cfg: DistConfig):

@@ -278,13 +278,13 @@ class EnsembleSimulation:
 
     def _consume_bundle(self, host: dict, diagnostics_every: int) -> None:
         for i in range(self.n_members):
-            n_done, n_sorts, n_rebuilds = consume_window_bundle(
+            counts = consume_window_bundle(
                 member_bundle(host, i), int(self.host_step[i]),
                 diagnostics_every, self.histories[i],
             )
-            self.host_step[i] += n_done
-            self.sorts[i] += n_sorts
-            self.rebuilds[i] += n_rebuilds
+            self.host_step[i] += counts.n_done
+            self.sorts[i] += counts.sorts
+            self.rebuilds[i] += counts.rebuilds
 
     # -- halt-and-grow ------------------------------------------------------
 

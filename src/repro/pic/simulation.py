@@ -48,6 +48,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core import (
@@ -248,53 +249,62 @@ def _pic_step(state: PICState, config: PICConfig) -> tuple[PICState, GPMAStats]:
     # 1. field gather (bins AND the carried slab are current w.r.t.
     #    pre-push positions: the slab the previous step staged for its
     #    deposition is exactly this step's gather staging)
-    e_p, b_p = _gather_fields(p.pos, state.fields, state.layout, state.slab, config)
+    with jax.named_scope("pic.gather"):
+        e_p, b_p = _gather_fields(p.pos, state.fields, state.layout, state.slab, config)
 
     # 2. push
-    u_new = boris_push(p.u, e_p, b_p, config.q_over_m, config.dt)
-    u_new = jnp.where(p.alive[:, None], u_new, p.u)
-    pos_new = wrap_periodic(advance_positions(p.pos, u_new, config.dt, config.grid.dx), config.grid.shape)
-    pos_new = jnp.where(p.alive[:, None], pos_new, p.pos)
+    with jax.named_scope("pic.push"):
+        u_new = boris_push(p.u, e_p, b_p, config.q_over_m, config.dt)
+        u_new = jnp.where(p.alive[:, None], u_new, p.u)
+        pos_new = wrap_periodic(advance_positions(p.pos, u_new, config.dt, config.grid.dx), config.grid.shape)
+        pos_new = jnp.where(p.alive[:, None], pos_new, p.pos)
 
     # 3. incremental sort / rebuild
-    new_cells = cell_index(pos_new, config.grid.shape)
-    if config.sort_mode in ("incremental",):
-        layout, stats = gpma_update(state.layout, new_cells, p.alive)
-    elif config.sort_mode in ("rebuild", "global"):
-        layout, overflow = build_bins(new_cells, p.alive, n_cells=config.grid.n_cells, capacity=config.capacity)
-        stats = GPMAStats(
-            n_moved=jnp.sum(new_cells != cell_index(p.pos, config.grid.shape)),
-            n_overflow=overflow,
-            n_empty=layout.n_empty(),
-            n_alive=jnp.sum(p.alive),
-        )
-    else:  # none
-        layout = state.layout
-        stats = GPMAStats(
-            n_moved=jnp.int32(0), n_overflow=jnp.int32(0),
-            n_empty=jnp.int32(0), n_alive=jnp.sum(p.alive),
-        )
+    with jax.named_scope("pic.gpma"):
+        new_cells = cell_index(pos_new, config.grid.shape)
+        if config.sort_mode in ("incremental",):
+            layout, stats = gpma_update(state.layout, new_cells, p.alive)
+        elif config.sort_mode in ("rebuild", "global"):
+            layout, overflow = build_bins(new_cells, p.alive, n_cells=config.grid.n_cells, capacity=config.capacity)
+            stats = GPMAStats(
+                n_moved=jnp.sum(new_cells != cell_index(p.pos, config.grid.shape)),
+                n_overflow=overflow,
+                n_empty=layout.n_empty(),
+                n_alive=jnp.sum(p.alive),
+                n_ranked=jnp.int32(new_cells.shape[0]),
+            )
+        else:  # none
+            layout = state.layout
+            stats = GPMAStats(
+                n_moved=jnp.int32(0), n_overflow=jnp.int32(0),
+                n_empty=jnp.int32(0), n_alive=jnp.sum(p.alive),
+                n_ranked=jnp.int32(0),
+            )
 
     # 3b. the step's ONE slab staging, consistent with (pos_new, layout):
     # consumed by the deposition below and carried for the next gather.
     # Velocity and charge-weight come first so the fused matrix path can
     # stage positions AND deposition values off a single slot-table gather
     # instead of a second gather inside the deposit kernel.
-    particles = dataclasses.replace(p, pos=pos_new, u=u_new)
-    gamma = lorentz_gamma(u_new)
-    v = u_new / gamma[:, None]
-    qw = config.charge * p.w * alive_f
-    values = None
-    if config.deposition == "matrix":
-        slab, values = bin_slab_staging(pos_new, v, qw, layout, grid_shape=config.grid.shape)
-    else:
-        slab = _state_slab(particles, layout, config)
+    with jax.named_scope("pic.push"):
+        particles = dataclasses.replace(p, pos=pos_new, u=u_new)
+        gamma = lorentz_gamma(u_new)
+        v = u_new / gamma[:, None]
+        qw = config.charge * p.w * alive_f
+    with jax.named_scope("pic.stage"):
+        values = None
+        if config.deposition == "matrix":
+            slab, values = bin_slab_staging(pos_new, v, qw, layout, grid_shape=config.grid.shape)
+        else:
+            slab = _state_slab(particles, layout, config)
 
     # 4. deposition at x^{n+1}, v^{n+1/2}
-    j = _deposit_current(pos_new, v, qw, layout, slab, new_cells, config, values=values)
+    with jax.named_scope("pic.deposit"):
+        j = _deposit_current(pos_new, v, qw, layout, slab, new_cells, config, values=values)
 
     # 5. fields
-    fields = maxwell_step(state.fields, j, dx=config.grid.dx, dt=config.dt, ckc_beta=config.ckc_beta)
+    with jax.named_scope("pic.maxwell"):
+        fields = maxwell_step(state.fields, j, dx=config.grid.dx, dt=config.dt, ckc_beta=config.ckc_beta)
 
     return PICState(fields=fields, particles=particles, layout=layout, step=state.step + 1, slab=slab), stats
 
@@ -314,19 +324,20 @@ def global_sort_device(state: PICState, config: PICConfig) -> tuple[PICState, ja
     bins (and the staging slab — the sort invalidates both), returning
     overflow as a traced int32 scalar so the sort can run inside jit /
     under `lax.cond` in the scan window."""
-    cells = cell_index(state.particles.pos, config.grid.shape)
-    perm = sort_permutation(cells, state.particles.alive)
-    # the sort is a piecewise-constant permutation: the index computation is
-    # stop-gradient, the value movement differentiable (grad.permutations) —
-    # bitwise identical to plain a[perm] in the forward pass
-    particles = permute_tree(state.particles, perm)
-    cells = cell_index(particles.pos, config.grid.shape)
-    layout, overflow = build_bins(cells, particles.alive, n_cells=config.grid.n_cells, capacity=config.capacity)
-    state = dataclasses.replace(
-        state, particles=particles, layout=layout,
-        slab=_state_slab(particles, layout, config),
-    )
-    return state, overflow.astype(jnp.int32)
+    with jax.named_scope("pic.global_sort"):
+        cells = cell_index(state.particles.pos, config.grid.shape)
+        perm = sort_permutation(cells, state.particles.alive)
+        # the sort is a piecewise-constant permutation: the index computation is
+        # stop-gradient, the value movement differentiable (grad.permutations) —
+        # bitwise identical to plain a[perm] in the forward pass
+        particles = permute_tree(state.particles, perm)
+        cells = cell_index(particles.pos, config.grid.shape)
+        layout, overflow = build_bins(cells, particles.alive, n_cells=config.grid.n_cells, capacity=config.capacity)
+        state = dataclasses.replace(
+            state, particles=particles, layout=layout,
+            slab=_state_slab(particles, layout, config),
+        )
+        return state, overflow.astype(jnp.int32)
 
 
 def global_sort(state: PICState, config: PICConfig) -> tuple[PICState, int]:
@@ -363,6 +374,7 @@ def _zeros_diag():
         "reason": i,
         "n_moved": i,
         "n_alive": i,
+        "n_ranked": i,
         "field_energy": f,
         "kinetic_energy": f,
     }
@@ -413,27 +425,30 @@ def _window_active_step(state, pstate, sorts, rebuilds, config: PICConfig,
     overflow_after = jnp.zeros((), jnp.int32)
 
     if config.sort_mode == "incremental":
-        mandatory = (stats.n_overflow > 0) if config.needs_bins else jnp.zeros((), bool)
-        do_pol, reason_pol, pstate_rec = policy_update(
-            pstate, policy,
-            n_moved=stats.n_moved, n_alive=stats.n_alive,
-            n_empty=stats.n_empty, n_slots=n_slots,
-        )
-        do_pol = do_pol & ~mandatory
-        do_sort = mandatory | do_pol
-        state, overflow_after = lax.cond(
-            do_sort, lambda s: global_sort_device(s, config), no_sort, state
-        )
-        # after a sort (mandatory or triggered) the counters reset; otherwise
-        # keep the recorded (post-record_step) state — exactly the host order
-        pstate = jax.tree.map(
-            lambda r, n: jnp.where(do_sort, r, n), policy_reset(), pstate_rec
-        )
-        sorts = sorts + do_pol.astype(jnp.int32)
-        rebuilds = rebuilds + mandatory.astype(jnp.int32)
-        reason = jnp.where(
-            mandatory, jnp.int32(REASON_OVERFLOW), reason_pol
-        ).astype(jnp.int32)
+        with jax.named_scope("pic.policy"):
+            mandatory = (stats.n_overflow > 0) if config.needs_bins else jnp.zeros((), bool)
+            do_pol, reason_pol, pstate_rec = policy_update(
+                pstate, policy,
+                n_moved=stats.n_moved, n_alive=stats.n_alive,
+                n_empty=stats.n_empty, n_slots=n_slots,
+            )
+            do_pol = do_pol & ~mandatory
+            do_sort = mandatory | do_pol
+        with jax.named_scope("pic.global_sort"):
+            state, overflow_after = lax.cond(
+                do_sort, lambda s: global_sort_device(s, config), no_sort, state
+            )
+        with jax.named_scope("pic.policy"):
+            # after a sort (mandatory or triggered) the counters reset; otherwise
+            # keep the recorded (post-record_step) state — exactly the host order
+            pstate = jax.tree.map(
+                lambda r, n: jnp.where(do_sort, r, n), policy_reset(), pstate_rec
+            )
+            sorts = sorts + do_pol.astype(jnp.int32)
+            rebuilds = rebuilds + mandatory.astype(jnp.int32)
+            reason = jnp.where(
+                mandatory, jnp.int32(REASON_OVERFLOW), reason_pol
+            ).astype(jnp.int32)
     elif config.sort_mode == "global":
         # per-step full sort including attribute permutation
         state, overflow_after = global_sort_device(state, config)
@@ -443,50 +458,52 @@ def _window_active_step(state, pstate, sorts, rebuilds, config: PICConfig,
         overflow_after = stats.n_overflow.astype(jnp.int32)
     # "none": nothing to decide
 
-    need_energies = with_energies or (health is not None and health.check_energy)
-    if need_energies:
-        field_e, kinetic = _energies(state, config)
-    else:
-        kinetic = jnp.zeros((), jnp.float32)
-        field_e = jnp.zeros((), jnp.float32)
+    with jax.named_scope("pic.diag"):
+        need_energies = with_energies or (health is not None and health.check_energy)
+        if need_energies:
+            field_e, kinetic = _energies(state, config)
+        else:
+            kinetic = jnp.zeros((), jnp.float32)
+            field_e = jnp.zeros((), jnp.float32)
 
-    diag = {
-        "active": jnp.ones((), bool),
-        "sorted": do_sort,
-        "reason": reason,
-        "n_moved": stats.n_moved.astype(jnp.int32),
-        "n_alive": stats.n_alive.astype(jnp.int32),
-        "field_energy": field_e if with_energies else jnp.zeros((), jnp.float32),
-        "kinetic_energy": kinetic if with_energies else jnp.zeros((), jnp.float32),
-    }
+        diag = {
+            "active": jnp.ones((), bool),
+            "sorted": do_sort,
+            "reason": reason,
+            "n_moved": stats.n_moved.astype(jnp.int32),
+            "n_alive": stats.n_alive.astype(jnp.int32),
+            "n_ranked": stats.n_ranked.astype(jnp.int32),
+            "field_energy": field_e if with_energies else jnp.zeros((), jnp.float32),
+            "kinetic_energy": kinetic if with_energies else jnp.zeros((), jnp.float32),
+        }
 
-    # health sentinel: pure reads of the post-step state — no arithmetic of
-    # the step itself changes, so a healthy sentinel-on run stays
-    # bit-identical to a sentinel-off run (tests/test_health.py pins this)
-    zero_i = jnp.zeros((), jnp.int32)
-    zero_f = jnp.zeros((), jnp.float32)
-    h_code, h_inv, h_meas, h_ref = zero_i, zero_i, zero_f, zero_f
-    if health is not None:
-        p = state.particles
-        ff = mf = zero_i
-        if health.check_nonfinite:
-            f = state.fields
-            ff = nonfinite_count([f.ex, f.ey, f.ez, f.bx, f.by, f.bz])
-            mf = nonfinite_count([p.u, p.pos], mask=p.alive)
-        h_code, h_inv, h_meas, h_ref = classify_health(
-            health,
-            fields_nonfinite=ff, momenta_nonfinite=mf,
-            charge=_total_charge(state), charge_ref=ref_charge,
-            energy=field_e + kinetic, energy_ref=ref_energy,
+        # health sentinel: pure reads of the post-step state — no arithmetic of
+        # the step itself changes, so a healthy sentinel-on run stays
+        # bit-identical to a sentinel-off run (tests/test_health.py pins this)
+        zero_i = jnp.zeros((), jnp.int32)
+        zero_f = jnp.zeros((), jnp.float32)
+        h_code, h_inv, h_meas, h_ref = zero_i, zero_i, zero_f, zero_f
+        if health is not None:
+            p = state.particles
+            ff = mf = zero_i
+            if health.check_nonfinite:
+                f = state.fields
+                ff = nonfinite_count([f.ex, f.ey, f.ez, f.bx, f.by, f.bz])
+                mf = nonfinite_count([p.u, p.pos], mask=p.alive)
+            h_code, h_inv, h_meas, h_ref = classify_health(
+                health,
+                fields_nonfinite=ff, momenta_nonfinite=mf,
+                charge=_total_charge(state), charge_ref=ref_charge,
+                energy=field_e + kinetic, energy_ref=ref_energy,
+            )
+
+        # persistent overflow (a bin fuller than `capacity` even after the sort)
+        # halts the window exactly as before; a health violation outranks it
+        # (a corrupt state must roll back before any capacity reaction)
+        step_code = jnp.where(
+            h_code != HALT_NONE, h_code,
+            jnp.where(overflow_after > 0, jnp.int32(HALT_BIN_OVERFLOW), jnp.int32(HALT_NONE)),
         )
-
-    # persistent overflow (a bin fuller than `capacity` even after the sort)
-    # halts the window exactly as before; a health violation outranks it
-    # (a corrupt state must roll back before any capacity reaction)
-    step_code = jnp.where(
-        h_code != HALT_NONE, h_code,
-        jnp.where(overflow_after > 0, jnp.int32(HALT_BIN_OVERFLOW), jnp.int32(HALT_NONE)),
-    )
     return state, pstate, step_code, sorts, rebuilds, diag, (h_inv, h_meas, h_ref)
 
 
@@ -506,12 +523,13 @@ def _pic_run_window_impl(state, pstate, n_target, fault_vec, config: PICConfig,
 
     # invariant references, captured at window entry: the sentinel compares
     # every step of the window against the state it started from
-    if health is not None:
-        ref_charge = _total_charge(state)
-        ref_fe, ref_ke = _energies(state, config)
-        ref_energy = ref_fe + ref_ke
-    else:
-        ref_charge = ref_energy = jnp.zeros((), jnp.float32)
+    with jax.named_scope("pic.diag"):
+        if health is not None:
+            ref_charge = _total_charge(state)
+            ref_fe, ref_ke = _energies(state, config)
+            ref_energy = ref_fe + ref_ke
+        else:
+            ref_charge = ref_energy = jnp.zeros((), jnp.float32)
 
     def body(carry, i):
         (state, pstate, halted, halt_code, halt_step, halt_inv, halt_meas,
@@ -532,25 +550,26 @@ def _pic_run_window_impl(state, pstate, n_target, fault_vec, config: PICConfig,
             st_in, pstate, sorts, rebuilds, config, policy, with_energies,
             health, ref_charge, ref_energy
         )
-        halted_step = step_code != HALT_NONE
-        diag = dict(diag, halt=halted_step)
-        keep = lambda old, new: jax.tree.map(lambda o, n: jnp.where(halted, o, n), old, new)
-        # first genuine halt of the window latches its full classification
-        # (code, absolute step, offending invariant, measured/reference)
-        first = halted_step & ~halted
-        carry = (
-            keep(state, new_state),
-            keep(pstate, new_pstate),
-            halted | halted_step | (i + 1 >= n_target),
-            jnp.where(first, step_code, halt_code),
-            jnp.where(first, new_state.step, halt_step),
-            jnp.where(first, hinfo[0], halt_inv),
-            jnp.where(first, hinfo[1], halt_meas),
-            jnp.where(first, hinfo[2], halt_ref),
-            jnp.where(halted, sorts, new_sorts),
-            jnp.where(halted, rebuilds, new_rebuilds),
-        )
-        return carry, keep(dict(_zeros_diag(), halt=jnp.zeros((), bool)), diag)
+        with jax.named_scope("pic.mask"):
+            halted_step = step_code != HALT_NONE
+            diag = dict(diag, halt=halted_step)
+            keep = lambda old, new: jax.tree.map(lambda o, n: jnp.where(halted, o, n), old, new)
+            # first genuine halt of the window latches its full classification
+            # (code, absolute step, offending invariant, measured/reference)
+            first = halted_step & ~halted
+            carry = (
+                keep(state, new_state),
+                keep(pstate, new_pstate),
+                halted | halted_step | (i + 1 >= n_target),
+                jnp.where(first, step_code, halt_code),
+                jnp.where(first, new_state.step, halt_step),
+                jnp.where(first, hinfo[0], halt_inv),
+                jnp.where(first, hinfo[1], halt_meas),
+                jnp.where(first, hinfo[2], halt_ref),
+                jnp.where(halted, sorts, new_sorts),
+                jnp.where(halted, rebuilds, new_rebuilds),
+            )
+            return carry, keep(dict(_zeros_diag(), halt=jnp.zeros((), bool)), diag)
 
     zero = jnp.zeros((), jnp.int32)
     zero_f = jnp.zeros((), jnp.float32)
@@ -622,15 +641,32 @@ _pic_run_window_donated = partial(
 _fetch_bundle = jax.device_get
 
 
+@dataclasses.dataclass(frozen=True)
+class WindowCounts:
+    """What one fetched window bundle adds to a driver's counters: its live
+    steps, global sorts and mandatory rebuilds, and over the live steps the
+    sums of the per-step ``n_moved``, ``n_alive`` and ``n_ranked`` and the
+    sorted steps by ``REASON_NAMES`` (every sort, whatever its trigger:
+    they sum to ``sorts + rebuilds`` under the incremental sort mode)."""
+
+    n_done: int
+    sorts: int
+    rebuilds: int
+    moved: int
+    particle_steps: int
+    ranked: int
+    sort_reasons: dict
+
+
 def consume_window_bundle(host: dict, host_step: int, diagnostics_every: int,
-                          history: list) -> tuple[int, int, int]:
+                          history: list) -> WindowCounts:
     """Host-side accounting for a FETCHED window bundle, shared by the
-    single-device and distributed windowed drivers: returns
-    ``(n_done, n_sorts, n_rebuilds)`` and appends every
-    ``diagnostics_every``-th per-step diagnostics record to ``history``."""
+    single-device, distributed and ensemble windowed drivers: returns the
+    window's `WindowCounts` and appends every ``diagnostics_every``-th
+    per-step diagnostics record to ``history``."""
     n_done = int(host["n_done"])
+    per = host["per_step"]
     if diagnostics_every:
-        per = host["per_step"]
         for i in range(n_done):
             step_abs = host_step + i + 1
             if step_abs % diagnostics_every == 0:
@@ -646,7 +682,39 @@ def consume_window_bundle(host: dict, host_step: int, diagnostics_every: int,
                     # snapshots state, which has no per-step churn counter)
                     "n_moved": int(per["n_moved"][i]),
                 })
-    return n_done, int(host["n_sorts"]), int(host["n_rebuilds"])
+    live = np.asarray(per["active"], bool)
+    total = lambda key: int(np.sum(np.asarray(per[key], np.int64)[live]))
+    reasons: dict[str, int] = {}
+    for code in np.asarray(per["reason"])[live & np.asarray(per["sorted"], bool)]:
+        name = REASON_NAMES[int(code)]
+        reasons[name] = reasons.get(name, 0) + 1
+    return WindowCounts(
+        n_done=n_done, sorts=int(host["n_sorts"]), rebuilds=int(host["n_rebuilds"]),
+        moved=total("n_moved"), particle_steps=total("n_alive"), ranked=total("n_ranked"),
+        sort_reasons=reasons,
+    )
+
+
+def add_counts(driver, *, moved: int = 0, particle_steps: int = 0, ranked: int = 0,
+               sort_reasons: dict | None = None) -> None:
+    """Add to a driver's counters beside ``sorts`` and ``rebuilds``:
+    ``moved`` (particles that changed cell), ``particle_steps`` (live
+    particles summed over steps), ``ranked`` (keys the GPMA rank sorted and
+    searched) and ``sort_reasons`` ({reason name: global sorts})."""
+    driver.moved += moved
+    driver.particle_steps += particle_steps
+    driver.ranked += ranked
+    for name, n in (sort_reasons or {}).items():
+        driver.sort_reasons[name] = driver.sort_reasons.get(name, 0) + n
+
+
+def commit_window_counts(driver, counts: WindowCounts) -> None:
+    """Add a consumed window's counts to a (single-simulation) driver."""
+    driver.sorts += counts.sorts
+    driver.rebuilds += counts.rebuilds
+    add_counts(driver, moved=counts.moved, particle_steps=counts.particle_steps,
+               ranked=counts.ranked, sort_reasons=counts.sort_reasons)
+    driver._host_step += counts.n_done
 
 
 def pic_run_window(
@@ -677,7 +745,7 @@ def pic_run_window(
     bundle holds window scalars (``n_done``, ``n_sorts``, ``n_rebuilds``,
     ``overflow_pending``) plus per-step arrays (``active``, ``sorted``,
     ``reason`` — see core.resort_policy.REASON_NAMES — ``n_moved``,
-    ``n_alive``, and, when `with_energies`, ``field_energy`` /
+    ``n_alive``, ``n_ranked``, and, when `with_energies`, ``field_energy`` /
     ``kinetic_energy``); fetch it with a single `jax.device_get`.
 
     If a global sort cannot absorb an overflowing bin (capacity too small),
@@ -929,6 +997,11 @@ class Simulation:
         self.policy_state = policy_init()
         self.sorts = 0
         self.rebuilds = 0
+        # sorter counters (docs/sim_loop.md, "Profiling a run"; add_counts)
+        self.moved = 0
+        self.particle_steps = 0
+        self.ranked = 0
+        self.sort_reasons: dict[str, int] = {}
         self.history: list[dict] = []
         self._host_step = 0  # host mirror of state.step (windowed path syncs nothing)
         # fault-tolerance plumbing (docs/robustness.md): halt/retry/restart
@@ -995,24 +1068,28 @@ class Simulation:
             self.state, stats = pic_step_donated(self.state, self.config)
             self._host_step += 1
             if self.config.sort_mode == "incremental":
-                n_overflow = int(stats.n_overflow)
-                n_empty = int(stats.n_empty)
+                # the per-step host sync: ONE transfer for all stat scalars
+                stats = jax.device_get(stats)
                 n_slots = self.config.grid.n_cells * self.config.capacity
-                if needs_bins and n_overflow > 0:
+                add_counts(self, moved=int(stats.n_moved), particle_steps=int(stats.n_alive),
+                           ranked=int(stats.n_ranked))
+                if needs_bins and stats.n_overflow > 0:
                     # mandatory rebuild (paper: overflow with low slots)
                     self.state, of = global_sort(self.state, self.config)
                     self.rebuilds += 1
+                    add_counts(self, sort_reasons={REASON_NAMES[REASON_OVERFLOW]: 1})
                     if of:
                         self._grow_capacity()
                     self.policy.reset()
                 else:
                     dtep = time.perf_counter() - t0
-                    perf = float(int(stats.n_alive)) / max(dtep, 1e-9)
+                    perf = float(stats.n_alive) / max(dtep, 1e-9)
                     self.policy.record_step(rebuilt=False, perf=perf)
-                    do, _reason = self.policy.should_sort(empty_ratio=n_empty / max(n_slots, 1))
+                    do, reason = self.policy.should_sort(empty_ratio=int(stats.n_empty) / max(n_slots, 1))
                     if do:
                         self.state, of = global_sort(self.state, self.config)
                         self.sorts += 1
+                        add_counts(self, sort_reasons={reason: 1})
                         if of:
                             self._grow_capacity()
                         self.policy.reset()
@@ -1048,26 +1125,25 @@ class Simulation:
                       fault_vec) -> dict:
         """Launch ONE compiled window (k live steps of a `window`-length
         program) and fetch its bundle — the single device->host sync."""
-        state, pstate, bundle = pic_run_window(
-            self.state, self.policy_state, self.config, window,
-            n_target=k,
-            policy=self.policy.config,
-            with_energies=bool(diagnostics_every),
-            health=self._health,
-            fault_vec=fault_vec,
-        )
+        tag = {"step": self._host_step, "k": k}
+        with jax.profiler.TraceAnnotation("pic.window.launch", **tag):
+            state, pstate, bundle = pic_run_window(
+                self.state, self.policy_state, self.config, window,
+                n_target=k,
+                policy=self.policy.config,
+                with_energies=bool(diagnostics_every),
+                health=self._health,
+                fault_vec=fault_vec,
+            )
         self.state, self.policy_state = state, pstate
-        return _fetch_bundle(bundle)
+        with jax.profiler.TraceAnnotation("pic.window.fetch", **tag):
+            return _fetch_bundle(bundle)
 
     def _consume_bundle(self, host: dict, diagnostics_every: int) -> int:
         """Commit a successful (or capacity-halted) window's accounting."""
-        n_done, n_sorts, n_rebuilds = consume_window_bundle(
-            host, self._host_step, diagnostics_every, self.history
-        )
-        self.sorts += n_sorts
-        self.rebuilds += n_rebuilds
-        self._host_step += n_done
-        return n_done
+        counts = consume_window_bundle(host, self._host_step, diagnostics_every, self.history)
+        commit_window_counts(self, counts)
+        return counts.n_done
 
     def _take_snapshot(self):
         """Deep-copy the window carry: the windowed call donates its input
