@@ -11,6 +11,7 @@ from repro.core.binning import (  # noqa: F401
     cell_coords,
     cell_index,
     choose_capacity,
+    rank_in_runs,
     sort_permutation,
 )
 from repro.core.deposition import (  # noqa: F401

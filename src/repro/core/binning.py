@@ -165,9 +165,37 @@ def cell_coords(n_cells: int, grid_shape) -> jax.Array:
     return jnp.stack([ix, iy, iz], axis=-1)
 
 
+def rank_in_runs(sorted_key) -> jax.Array:
+    """Position of each entry within its run of equal keys: int32[n].
+
+    ``sorted_key`` is sorted, so ``i - (index where i's run starts)`` is
+    the rank, and that start is the running maximum of the run-start
+    indices: one max-scan, no search and no gather. Equal, entry for
+    entry, to ``arange(n) - searchsorted(sorted_key, sorted_key, "left")``
+    for any sorted int keys.
+
+    The scan doubles its reach each pass: log2(n) elementwise maxima of
+    the array and its shifted copy, every op under the caller's scope.
+    (``lax.cummax`` lowers to a reduce-window that the TPU compiler splits
+    into ops with no scope, and takes tens of seconds to compile at 2M
+    keys; ``lax.associative_scan`` compiles slower still.)
+    """
+    n = sorted_key.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.concatenate([jnp.ones((min(n, 1),), bool), sorted_key[1:] != sorted_key[:-1]])
+    first = jnp.where(start, idx, 0)
+    shift = 1
+    while shift < n:
+        first = jnp.maximum(first, jnp.pad(first[:-shift], (shift, 0)))
+        shift *= 2
+    return idx - first
+
+
 @partial(jax.jit, static_argnames=("n_cells", "capacity"))
 def build_bins(cell_ids, alive, *, n_cells: int, capacity: int):
-    """Counting-sort rebuild of the binned layout.
+    """Counting-sort rebuild of the binned layout: key argsort, then each
+    particle's rank within its cell from a run-start max-scan
+    (`rank_in_runs`).
 
     Dead particles (alive == False) get particle_slot = -1. Particles whose
     within-cell rank exceeds `capacity` overflow: they are left unslotted and
@@ -179,13 +207,7 @@ def build_bins(cell_ids, alive, *, n_cells: int, capacity: int):
     key = jnp.where(alive, cell_ids, n_cells)  # dead -> sentinel bin
     order = jnp.argsort(key, stable=True)
     sorted_key = key[order]
-    # rank within cell = position - first position of this cell id. The
-    # search is traced without its jit wrapper: the GPMA rank makes the same
-    # jitted call at the same shapes, JAX lowers one loop body for both, and
-    # the TPU compiler then labels both copies with the rank's scope
-    # (docs/sim_loop.md, "Profiling a run"). The ops are the same.
-    first = jnp.searchsorted.__wrapped__(sorted_key, sorted_key, side="left")
-    rank = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
+    rank = rank_in_runs(sorted_key)
 
     in_range = (sorted_key < n_cells) & (rank < capacity)
     overflow = jnp.sum((sorted_key < n_cells) & (rank >= capacity))

@@ -11,7 +11,8 @@ updates over the whole tile. The expensive thing this avoids — exactly as in
 the paper — is permuting the SoA attribute arrays (8+ streams of N_p values)
 and re-establishing locality every step; the incremental path touches only
 the int32 index structure. Rank assignment inside target bins uses one
-key-only argsort (int32 keys, a counting-sort analogue), never attribute
+key-only argsort (int32 keys, a counting-sort analogue) and a run-start
+max-scan over the sorted keys (`binning.rank_in_runs`), never attribute
 data. Bin-borrowing (paper's pointer-chasing fallback) is replaced by
 rebuild-on-overflow, preserving the amortized bound under the same CFL
 assumption.
@@ -30,7 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.binning import INVALID, BinnedLayout
+from repro.core.binning import INVALID, BinnedLayout, rank_in_runs
 
 
 @jax.tree_util.register_dataclass
@@ -49,7 +50,7 @@ class GPMAStats:
     n_overflow: jax.Array    # inserts that found no gap (-> rebuild needed)
     n_empty: jax.Array       # empty slots after update
     n_alive: jax.Array       # live particles
-    n_ranked: jax.Array      # keys the rank stage sorts and searches (all
+    n_ranked: jax.Array      # keys the rank stage sorts and scans (all
                              # n: only the `n_moved` that changed cell need
                              # a rank, the rest sort to the sentinel key)
 
@@ -95,8 +96,7 @@ def gpma_update(layout: BinnedLayout, new_cell, alive):
         key = jnp.where(needs_insert, new_cell, n_cells)
         order = jnp.argsort(key, stable=True)            # key-only sort (index data)
         sorted_key = key[order]
-        first = jnp.searchsorted(sorted_key, sorted_key, side="left")
-        rank = (jnp.arange(n) - first).astype(jnp.int32)
+        rank = rank_in_runs(sorted_key)
 
     with jax.named_scope("pic.gpma.gaps"):
         # r-th gap of each bin (stable argsort over the small capacity axis).

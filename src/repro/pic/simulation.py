@@ -700,7 +700,7 @@ def add_counts(driver, *, moved: int = 0, particle_steps: int = 0, ranked: int =
     """Add to a driver's counters beside ``sorts`` and ``rebuilds``:
     ``moved`` (particles that changed cell), ``particle_steps`` (live
     particles summed over steps), ``ranked`` (keys the GPMA rank sorted and
-    searched) and ``sort_reasons`` ({reason name: global sorts})."""
+    scanned) and ``sort_reasons`` ({reason name: global sorts})."""
     driver.moved += moved
     driver.particle_steps += particle_steps
     driver.ranked += ranked

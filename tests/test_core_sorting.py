@@ -1,15 +1,20 @@
 """GPMA incremental sorter + binning: structural invariants and equivalence
 with a full rebuild (hypothesis properties live in test_properties.py)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+import repro.core.binning as binning
+import repro.core.gpma as gpma
 from repro.core import (
     ResortPolicy,
     SortPolicyConfig,
     build_bins,
     cell_index,
     gpma_update,
+    rank_in_runs,
     sort_permutation,
 )
 
@@ -101,6 +106,70 @@ def test_gpma_overflow_flagged_not_lost_silently():
     pslot = np.asarray(new_layout.particle_slot)
     assert np.sum(pslot >= 0) == CAP
     check_layout_invariants(new_layout, cells1, jnp.asarray(pslot >= 0))
+
+
+def _rank_keys(case):
+    rng = np.random.default_rng(7)
+    if case == "sentinel-run":
+        n, n_cells = 5000, 64
+        keys = np.where(rng.random(n) < 0.3, rng.integers(0, n_cells, n), n_cells)
+    elif case == "all-equal":
+        keys = np.full(777, 5)
+    elif case == "all-distinct":
+        keys = rng.permutation(4096) * 3
+    elif case == "one":
+        keys = np.asarray([9])
+    elif case == "empty":
+        keys = np.zeros(0)
+    else:  # the bench cells' rank: 2,097,152 keys, 1.5% moved, the rest sentinel
+        n, n_cells = 2_097_152, 262_144
+        keys = np.where(rng.random(n) < 0.015, rng.integers(0, n_cells, n), n_cells)
+    return np.sort(keys).astype(np.int32)
+
+
+@pytest.mark.parametrize("case", ["sentinel-run", "all-equal", "all-distinct", "one", "empty", "bench-size"])
+def test_rank_in_runs_matches_the_search(case):
+    """The run-start max-scan gives the within-run rank the binary search gave."""
+    keys = _rank_keys(case)
+    want = np.arange(keys.size) - np.searchsorted(keys, keys, side="left")
+    got = np.asarray(jax.jit(rank_in_runs)(jnp.asarray(keys)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
+def _search_rank(sorted_key):
+    """The rank as it was computed before the max-scan: a binary search."""
+    first = jnp.searchsorted(sorted_key, sorted_key, side="left")
+    return (jnp.arange(sorted_key.shape[0]) - first).astype(jnp.int32)
+
+
+@pytest.mark.parametrize(
+    "n_cells,capacity,overflows", [(24, 16, True), (64, 48, False)], ids=["overflowing", "roomy"])
+def test_rank_scan_gives_the_search_layout(monkeypatch, n_cells, capacity, overflows):
+    """`build_bins` and `gpma_update` give the same slots, particle_slot and
+    stats with the max-scan rank as with the binary search, through deaths,
+    moves and overflow."""
+    rng = np.random.default_rng(11)
+    n = 2000
+    cells0 = jnp.asarray(rng.integers(0, n_cells, n), jnp.int32)
+    alive0 = jnp.asarray(rng.random(n) > 0.05)
+    move = rng.random(n) < 0.2
+    cells1 = jnp.asarray(np.where(move, rng.integers(0, n_cells, n), np.asarray(cells0)), jnp.int32)
+    alive1 = alive0 & jnp.asarray(rng.random(n) > 0.05)
+
+    def run(bins, update):
+        layout, overflow = bins(cells0, alive0, n_cells=n_cells, capacity=capacity)
+        new, stats = update(layout, cells1, alive1)
+        return jax.tree.map(np.asarray, (layout, overflow, new, stats))
+
+    got = run(build_bins, gpma_update)
+    # the unjitted bodies, which look the rank up when they run
+    monkeypatch.setattr(binning, "rank_in_runs", _search_rank)
+    monkeypatch.setattr(gpma, "rank_in_runs", _search_rank)
+    want = run(build_bins.__wrapped__, gpma_update.__wrapped__)
+    assert (int(want[1]) > 0) == overflows and (int(want[3].n_overflow) > 0) == overflows
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_sort_permutation_orders_cells():

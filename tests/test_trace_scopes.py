@@ -94,15 +94,18 @@ def test_every_phase_scope_appears(window_hlo):
     assert set(PHASES) <= innermost, sorted(set(PHASES) - innermost)
 
 
-def test_searchsorted_loop_carries_the_rank_scope(window_hlo):
-    """The binary search runs in the GPMA rank every step and in the global
-    sort's bin build; each loop, and every op of its body, carries its own
-    phase (one loop body shared by both would carry one phase for both)."""
-    loop_ops = [n for n in OP_NAME.findall(window_hlo) if "vmap()/while" in n]
-    rank = [n for n in loop_ops if "jit(gpma_update)" in n]
-    bins = [n for n in loop_ops if "jit(build_bins)" in n]
-    assert rank, "no binary search loop in the GPMA update"
-    assert bins, "no binary search loop in the global sort's bin build"
+def test_rank_scan_has_no_loop_and_carries_its_phase(window_hlo):
+    """The within-bin rank runs in the GPMA rank every step and in the
+    global sort's bin build: a run-start max-scan (`rank_in_runs`), with no
+    search loop left, whose ops carry their caller's phase."""
+    names = OP_NAME.findall(window_hlo)
+    loops = [n for n in names if re.search(r"jit\((gpma_update|build_bins)\)/.*while", n)]
+    assert not loops, set(loops)
+    scan = [n for n in names if n.endswith("/max")]
+    rank = [n for n in scan if "jit(gpma_update)" in n]
+    bins = [n for n in scan if "jit(build_bins)" in n]
+    assert rank, "no max-scan in the GPMA update"
+    assert bins, "no max-scan in the global sort's bin build"
     assert all(_scopes(n)[-1] == "pic.gpma.rank" for n in rank), set(rank)
     assert all(_scopes(n)[-1] == "pic.global_sort" for n in bins), set(bins)
 
